@@ -59,6 +59,36 @@ from repro.data.windows import make_windows
 REPO_ROOT = Path(__file__).resolve().parent.parent
 
 
+def _run_cpu_child(prog: str, marker: str, what: str):
+    """Run a virtual-device rehearsal in a child interpreter; return the JSON
+    it printed after ``marker``.
+
+    The child is a CPU rehearsal by design (``JAX_PLATFORMS=cpu`` in its own
+    environment): this process has already touched JAX, and on a machine
+    with a chip the parent holds it — a child reaching for it would fail or
+    hang.
+    """
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(REPO_ROOT / "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    env.pop("XLA_FLAGS", None)
+    p = subprocess.run(
+        [sys.executable, "-c", prog],
+        capture_output=True,
+        text=True,
+        env=env,
+        cwd=REPO_ROOT,
+        timeout=900,
+    )
+    lines = [ln for ln in p.stdout.splitlines() if ln.startswith(marker + " ")]
+    if p.returncode != 0 or not lines:
+        raise RuntimeError(
+            f"{what} subprocess failed (rc={p.returncode})\n"
+            f"stdout:\n{p.stdout[-2000:]}\nstderr:\n{p.stderr[-2000:]}"
+        )
+    return json.loads(lines[0][len(marker) + 1 :])
+
+
 def run(slots: int = 8, n_ticks: int = 8, repeats: int = 3, smoke: bool = False):
     """Returns (csv_rows, metrics dict). Fixed seeds; see module docstring."""
     if smoke:
@@ -484,24 +514,7 @@ def run_mesh_scaling(
     prog = _MESH_SNIPPET.format(
         device_count=device_count, slots=slots, n_ticks=n_ticks, repeats=repeats
     )
-    env = dict(os.environ)
-    env["PYTHONPATH"] = str(REPO_ROOT / "src")
-    env.pop("XLA_FLAGS", None)
-    p = subprocess.run(
-        [sys.executable, "-c", prog],
-        capture_output=True,
-        text=True,
-        env=env,
-        cwd=REPO_ROOT,
-        timeout=900,
-    )
-    marker = [ln for ln in p.stdout.splitlines() if ln.startswith("MESHBENCH ")]
-    if p.returncode != 0 or not marker:
-        raise RuntimeError(
-            f"mesh-scaling subprocess failed (rc={p.returncode})\n"
-            f"stdout:\n{p.stdout[-2000:]}\nstderr:\n{p.stderr[-2000:]}"
-        )
-    stats = {int(k): v for k, v in json.loads(marker[0][len("MESHBENCH ") :]).items()}
+    stats = {int(k): v for k, v in _run_cpu_child(prog, "MESHBENCH", "mesh-scaling").items()}
     tps = {m: s["tps"] for m, s in stats.items()}
     dev = {m: s["device"] for m, s in stats.items()}
     scaling = tps[2] / tps[1]
@@ -670,24 +683,7 @@ def run_chaos(
         checkpoint_period=checkpoint_period,
         max_ticks=60,
     )
-    env = dict(os.environ)
-    env["PYTHONPATH"] = str(REPO_ROOT / "src")
-    env.pop("XLA_FLAGS", None)
-    p = subprocess.run(
-        [sys.executable, "-c", prog],
-        capture_output=True,
-        text=True,
-        env=env,
-        cwd=REPO_ROOT,
-        timeout=900,
-    )
-    marker = [ln for ln in p.stdout.splitlines() if ln.startswith("CHAOSBENCH ")]
-    if p.returncode != 0 or not marker:
-        raise RuntimeError(
-            f"chaos-drill subprocess failed (rc={p.returncode})\n"
-            f"stdout:\n{p.stdout[-2000:]}\nstderr:\n{p.stderr[-2000:]}"
-        )
-    stats = json.loads(marker[0][len("CHAOSBENCH ") :])
+    stats = _run_cpu_child(prog, "CHAOSBENCH", "chaos-drill")
     frac = stats["recovered_streams_fraction"]
     rows = [
         (

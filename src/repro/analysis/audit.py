@@ -28,6 +28,7 @@ exits nonzero on any error-rule finding.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import json
 import os
@@ -46,7 +47,7 @@ from repro.core.quant import make_sigmoid_table, make_tanh_table, quantize_int8
 from repro.kernels.mr_step import ref as mr_ref
 from repro.kernels.mr_step import tiling
 from repro.optim import adamw_init
-from repro.parallel.rules import predict_tick_collectives
+from repro.parallel.rules import predict_tick_collectives, use_mesh_rules
 
 DEFAULT_RULES = ("R1", "R2", "R3", "R4", "R5")
 
@@ -249,19 +250,28 @@ def audit_plan(
         new_u = jnp.zeros((spec.n_slots, scfg.chunk, cfg.input_dim), jnp.float32)
         banked_tick = plan.lowering.tick_kernel == "banked"
         quant_tick = plan.lowering.quant_serving and scfg.steps_per_tick == 0
-        if banked_tick:
-            lowered = stream_mod.tick_banked.lower(
-                state,
-                new_y,
-                new_u,
-                key,
-                cfg=cfg,
-                scfg=scfg,
-                quant=quant_tick,
-                slots_per_bank=plan.lowering.tick_slots_per_bank or 1,
-            )
-        else:
-            lowered = stream_mod.tick.lower(state, new_y, new_u, key, cfg=cfg, scfg=scfg)
+
+        def mesh_ctx():
+            # programs are traced as the service calls them: inside the
+            # plan's slot mesh, where the tick's kernel section runs per shard
+            if plan.mesh is None:
+                return contextlib.nullcontext()
+            return use_mesh_rules(plan.mesh, stream_mod.SLOT_RULES)
+
+        with mesh_ctx():
+            if banked_tick:
+                lowered = stream_mod.tick_banked.lower(
+                    state,
+                    new_y,
+                    new_u,
+                    key,
+                    cfg=cfg,
+                    scfg=scfg,
+                    quant=quant_tick,
+                    slots_per_bank=plan.lowering.tick_slots_per_bank or 1,
+                )
+            else:
+                lowered = stream_mod.tick.lower(state, new_y, new_u, key, cfg=cfg, scfg=scfg)
         text = _compiled_text(lowered)
         run("R1", "tick", R.check_donation, text, ("state",))
         run("R3", "tick", R.check_host_transfers, text, host_allowlist)
@@ -311,19 +321,20 @@ def audit_plan(
             )
             if plan.mesh is not None:
                 control = control_mod.shard_control(control, plan.mesh)
-            lowered = control_mod.tick_device.lower(
-                state,
-                control,
-                new_y,
-                new_u,
-                key,
-                cfg=cfg,
-                scfg=scfg,
-                kernel=plan.lowering.tick_kernel,
-                quant=quant_tick,
-                slots_per_bank=plan.lowering.tick_slots_per_bank or 1,
-                shards=shards,
-            )
+            with mesh_ctx():
+                lowered = control_mod.tick_device.lower(
+                    state,
+                    control,
+                    new_y,
+                    new_u,
+                    key,
+                    cfg=cfg,
+                    scfg=scfg,
+                    kernel=plan.lowering.tick_kernel,
+                    quant=quant_tick,
+                    slots_per_bank=plan.lowering.tick_slots_per_bank or 1,
+                    shards=shards,
+                )
             text = _compiled_text(lowered)
             run("R1", "tick_device", R.check_donation, text, ("state", "control"))
             run("R3", "tick_device", R.check_host_transfers, text, host_allowlist)
@@ -499,26 +510,7 @@ def _run_mesh_cell(
         print("AUDITCELL " + json.dumps(report.to_json()))
         """
     )
-    env = dict(os.environ)
-    src_root = os.path.dirname(os.path.dirname(os.path.dirname(__file__)))
-    env["PYTHONPATH"] = os.pathsep.join(p for p in (src_root, env.get("PYTHONPATH", "")) if p)
-    proc = subprocess.run(
-        [sys.executable, "-c", snippet],
-        capture_output=True,
-        text=True,
-        env=env,
-        timeout=560,
-        check=False,
-    )
-    for line in proc.stdout.splitlines():
-        if line.startswith("AUDITCELL "):
-            return json.loads(line.split(" ", 1)[1])
-    return {
-        "verdict": "infra-error",
-        "checked": {},
-        "findings": [],
-        "stderr": proc.stderr[-2000:],
-    }
+    return _run_cpu_cell(snippet)
 
 
 def _run_restored_cell(survivors: int, rules: tuple[str, ...]) -> dict:
@@ -566,9 +558,21 @@ def _run_restored_cell(survivors: int, rules: tuple[str, ...]) -> dict:
         print("AUDITCELL " + json.dumps(report.to_json()))
         """
     )
+    return _run_cpu_cell(snippet)
+
+
+def _run_cpu_cell(snippet: str) -> dict:
+    """Run one meshed audit cell in a child interpreter on CPU virtual devices.
+
+    The child is a CPU rehearsal by design (``JAX_PLATFORMS=cpu`` in its own
+    environment): a parent that has touched an accelerator holds it, and a
+    child reaching for the same chip would fail or hang. A child that prints
+    no AUDITCELL line crashed: its verdict is ``"infra-error"``.
+    """
     env = dict(os.environ)
     src_root = os.path.dirname(os.path.dirname(os.path.dirname(__file__)))
     env["PYTHONPATH"] = os.pathsep.join(p for p in (src_root, env.get("PYTHONPATH", "")) if p)
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-c", snippet],
         capture_output=True,
@@ -650,11 +654,9 @@ def main(argv=None) -> int:
         nonlocal n_err, n_warn
         cells.append({"cell": label, **cell})
         if cell["verdict"] == "infra-error":
-            # a crashed subprocess is an environment problem, not a
-            # contract violation — surface it loudly but do not fail
-            # warn-mode CI
-            n_warn += 1
-            print(f"WARN  {label} mesh cell failed to run:\n{cell.get('stderr', '')}")
+            # a cell that crashed checked nothing: the matrix cannot pass
+            n_err += 1
+            print(f"ERROR {label} mesh cell failed to run:\n{cell.get('stderr', '')}")
             return
         for f in cell["findings"]:
             rule = f["rule"]

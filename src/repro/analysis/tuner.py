@@ -401,16 +401,13 @@ def _lower_tick(spec, cand: Candidate):
 def _xla_costs(compiled) -> tuple[float | None, float | None]:
     """(flops, bytes accessed) from Compiled.cost_analysis(), defensively.
 
-    jax 0.4.x wraps the per-device dict in a list; either spelling (and a
-    backend that raises) degrades to (None, None) — the parse-based score
-    is the primary signal, this is the cross-check.
+    A backend that raises or reports nothing degrades to (None, None) — the
+    parse-based score is the primary signal, this is the cross-check.
     """
     try:
         cost = compiled.cost_analysis()
     except Exception:
         return None, None
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     if not isinstance(cost, dict):
         return None, None
     return cost.get("flops"), cost.get("bytes accessed")

@@ -13,7 +13,7 @@ that story. It takes one declarative :class:`RecoverySpec` and produces a
 - the jitted, donated programs for the spec's execution mode (the engine's
   epoch scan, the vmapped multi-system recovery, the streaming tick);
 - for stream mode, a device mesh over the slot axis — ``SlotState`` is
-  sharded across it (``jax.set_mesh`` shim + the ``parallel/`` rule table),
+  sharded across it (``parallel.make_mesh`` + the ``parallel/`` rule table),
   with ``mesh_slots=1`` degenerating to the single-device path — so one
   service scales past a single chip's VMEM/HBM.
 
@@ -43,6 +43,7 @@ from repro.core.stream import RecoveryService, StreamConfig
 from repro.kernels import runtime as rt
 from repro.kernels.mr_step import tiling
 from repro.optim import adamw_init
+from repro.parallel import make_mesh
 
 
 @dataclasses.dataclass(frozen=True)
@@ -463,7 +464,7 @@ def compile_plan(spec: RecoverySpec, audit: str = "off", tune: str = "off") -> R
                 f"device(s); set XLA_FLAGS=--xla_force_host_platform_device_count "
                 f"for CPU virtual devices"
             )
-        mesh = jax.make_mesh((spec.mesh_slots,), ("slots",))
+        mesh = make_mesh((spec.mesh_slots,), ("slots",))
 
     # the jitted donated programs for this spec's mode — static arguments are
     # bound NOW so every later call hits the same executable

@@ -75,6 +75,23 @@ from repro.parallel import named_sharding, use_mesh_rules
 SLOT_RULES: dict[str, list[tuple[str, ...]]] = {"slots": [("slots",)]}
 
 
+def _slot_local(fn):
+    """``fn`` (batched over the leading slot axis of every argument and
+    result) run on each device's own slots when a slot mesh is active.
+
+    Mosaic kernels cannot be partitioned automatically, so on a sharded slot
+    axis the tick's per-slot section runs under ``shard_map``: each device
+    computes the slots it holds, the same per-slot math as on one device,
+    with no collective. Off a mesh (or on the trivial one) ``fn`` is
+    returned unchanged.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.shape.get("slots", 1) == 1:
+        return fn
+    slots = jax.sharding.PartitionSpec("slots")
+    return jax.shard_map(fn, in_specs=slots, out_specs=slots, check_vma=False)
+
+
 @dataclasses.dataclass(frozen=True)
 class StreamConfig:
     """Static service configuration (hashable: usable as a jit static arg)."""
@@ -262,6 +279,18 @@ def _recover_steps(params, opt, yw, uw, key, steps0, *, cfg: MRConfig, scfg: Str
     return params, opt, theta.mean(axis=0), recon[-1]
 
 
+def _train_slots(state: SlotState, yw, uw, key, *, cfg: MRConfig, scfg: StreamConfig):
+    """K recovery steps for every slot: (params, opt, Theta, last recon MSE).
+
+    Each slot's sampling key folds in its GLOBAL slot index, so a slot trains
+    identically whichever device of the slot mesh holds it."""
+    n_slots = yw.shape[0]
+    keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(jnp.arange(n_slots))
+    return _slot_local(
+        jax.vmap(lambda p, o, y, u, k, s: _recover_steps(p, o, y, u, k, s, cfg=cfg, scfg=scfg))
+    )(state.params, state.opt, yw, uw, keys, state.steps)
+
+
 def _tick_impl(
     state: SlotState,
     new_y: jnp.ndarray,
@@ -281,16 +310,14 @@ def _tick_impl(
     )
 
     if scfg.steps_per_tick:
-        n_slots = buf_y.shape[0]
-        keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(jnp.arange(n_slots))
-        params, opt, theta, recon = jax.vmap(
-            lambda p, o, y, u, k, s: _recover_steps(p, o, y, u, k, s, cfg=cfg, scfg=scfg)
-        )(state.params, state.opt, yw, uw, keys, state.steps)
+        params, opt, theta, recon = _train_slots(state, yw, uw, key, cfg=cfg, scfg=scfg)
         loss = jnp.where(state.active, recon, jnp.inf)
     else:
         # serve/monitor tick: no optimizer steps, readout only
         params, opt, loss = state.params, state.opt, state.loss
-        theta = jax.vmap(lambda p, y, u: mr_forward(p, cfg, y, u)[0].mean(axis=0))(params, yw, uw)
+        theta = _slot_local(
+            jax.vmap(lambda p, y, u: mr_forward(p, cfg, y, u)[0].mean(axis=0))
+        )(params, yw, uw)
 
     # EMA-smoothed readout: the window set (and its normalization) shifts a
     # little every tick, so the raw per-tick Theta jitters even after the
@@ -378,23 +405,21 @@ def _tick_banked_impl(
         yw, uw = jax.vmap(lambda y, u, mu, sd: _slot_windows(y, u, mu, sd, scfg))(
             buf_y, buf_u, state.mean, state.scale
         )
-        n_slots = buf_y.shape[0]
-        keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(jnp.arange(n_slots))
         # the in-scan forward readout is unused here (the banked kernel reads
         # out below, from the post-training params) — XLA dead-code-eliminates
         # it, leaving exactly the composite tick's training program
-        params, opt, _, recon = jax.vmap(
-            lambda p, o, y, u, k, s: _recover_steps(p, o, y, u, k, s, cfg=cfg, scfg=scfg)
-        )(state.params, state.opt, yw, uw, keys, state.steps)
+        params, opt, _, recon = _train_slots(state, yw, uw, key, cfg=cfg, scfg=scfg)
         loss = jnp.where(state.active, recon, jnp.inf)
     else:
         params, opt, loss = state.params, state.opt, state.loss
 
     seed = (state.steps == 0) & jnp.isinf(state.delta)
-    buf_y, buf_u, theta, delta = mr_tick(
+
+    def serve(params, *slot_arrays):
+        return mr_tick(params, cfg, scfg, *slot_arrays, quant=quant, slots_per_bank=slots_per_bank)
+
+    buf_y, buf_u, theta, delta = _slot_local(serve)(
         params,
-        cfg,
-        scfg,
         state.buf_y,
         state.buf_u,
         new_y,
@@ -404,8 +429,6 @@ def _tick_banked_impl(
         state.theta,
         seed,
         state.active,
-        quant=quant,
-        slots_per_bank=slots_per_bank,
     )
     delta = jnp.where(state.active, delta, jnp.inf)
     steps = state.steps + scfg.steps_per_tick
@@ -650,7 +673,7 @@ class RecoveryService:
             self._inflight = [set() for _ in range(control.shards)]
 
     def _mesh_ctx(self):
-        """Activate the slot mesh (jax.set_mesh shim via parallel/) around
+        """Activate the slot mesh (jax.set_mesh via parallel/) around
         every compiled-program call; a no-op on the trivial mesh."""
         if self.mesh is None:
             return contextlib.nullcontext()

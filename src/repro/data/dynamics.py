@@ -319,7 +319,17 @@ def generate_trajectory(
     ys_fine = odeint(spec.dynamics, y0, ts_fine, us=us_fine, method="rk4")
     sl = slice(None, None, oversample)
     ts, ys, us = np.asarray(ts_fine[sl]), np.asarray(ys_fine[sl]), np.asarray(us_fine[sl])
+    return ts, add_sensor_noise(ys, noise_std, seed), us.astype(np.float32)
+
+
+def add_sensor_noise(ys: np.ndarray, noise_std: float, seed: int) -> np.ndarray:
+    """Gaussian sensor noise scaled per channel by the clean signal's spread.
+
+    A fleet of tenants streaming the same system shares one clean
+    trajectory and differs only in this draw, so a fleet integrates each
+    system once and calls this per stream.
+    """
     if noise_std > 0:
         rng = np.random.default_rng(seed)
         ys = ys + noise_std * ys.std(axis=0, keepdims=True) * rng.standard_normal(ys.shape)
-    return ts, ys.astype(np.float32), us.astype(np.float32)
+    return ys.astype(np.float32)

@@ -13,8 +13,7 @@ Each kernel package ships kernel.py (pallas kernel body + VMEM tiling),
 ops.py (jit'd public wrapper with interpret/XLA fallbacks) and ref.py (pure-jnp
 oracle used by the allclose test sweeps).
 
-runtime.py is the shared kernel runtime: Pallas API-drift shims
-(CompilerParams/TPUCompilerParams, BlockSpec argument order, VMEM scratch)
-behind one pallas_call_compat entry point, plus the TPU/interpret/reference
-dispatch policy every ops.py consults.
+runtime.py is the shared kernel runtime: the one pallas_call constructor
+(spec pairs, VMEM scratch, SMEM operands, compiler params), plus the
+TPU/interpret/reference dispatch policy every ops.py consults.
 """

@@ -127,7 +127,7 @@ def flash_attention_pallas(
         q_offset=q_offset,
         num_kv_blocks=nk,
     )
-    return rt.pallas_call_compat(
+    return rt.pallas_call(
         kernel,
         grid=(B, QH, nq, nk),
         in_specs=[

@@ -22,9 +22,13 @@ TPU re-derivation of the paper's FPGA dataflow (§5):
   hidden state held on-chip           ->   h carried in a VMEM scratch across
                                            grid steps (never round-trips HBM)
 
-Layouts: xs is batch-major [B, T, D]; the grid iterates batch tiles in the
-OUTER dimension so each tile completes its full time scan with the same
-scratch buffer (t==0 re-initializes from h0).
+Layouts: the wrappers take batch-major xs [B, T, D] and hand the kernel a
+time-major view [T, B, D], so each grid step streams one ``(bb, D)`` x_t
+tile (the time dim squeezed) and writes one ``(bb, H)`` h_t tile of the
+time-major output — blocks whose last two dims are the full array dims or
+(8, 128)-aligned, as Mosaic requires. Per-step dts live in SMEM. The grid
+iterates batch tiles in the OUTER dimension so each tile completes its full
+time scan with the same scratch buffer (t==0 re-initializes from h0).
 """
 
 from __future__ import annotations
@@ -65,15 +69,15 @@ def _gru_step_math(x, h, wx, wh, b, time_scale, dt, *, flow: bool, hidden: int):
 
 def _gru_scan_kernel(
     # inputs
-    xs_ref,  # [bb, 1, D]   x_t tile (double-buffered by Mosaic)
+    xs_ref,  # [bb, D]      x_t tile (time-major stream, double-buffered by Mosaic)
     h0_ref,  # [bb, H]
     wx_ref,  # [D, 3H]      VMEM-resident across the whole scan
     wh_ref,  # [H, 3H]
     b_ref,  # [1, 3H]
     ts_ref,  # [1, H]       time-gate log-scales
-    dts_ref,  # [1, 1]      dt_t
+    dts_ref,  # SMEM [T, 1] per-step dt
     # outputs
-    hs_ref,  # [bb, 1, H]
+    hs_ref,  # [bb, H]      h_t tile of the time-major output
     # scratch
     h_scr,  # VMEM [bb, H] f32 — the on-chip hidden state ("BRAM" analogue)
     *,
@@ -86,7 +90,7 @@ def _gru_scan_kernel(
     def _init():
         h_scr[...] = h0_ref[...].astype(jnp.float32)
 
-    x = xs_ref[:, 0, :]
+    x = xs_ref[...]
     h = h_scr[...]
     h_new = _gru_step_math(
         x,
@@ -95,12 +99,12 @@ def _gru_scan_kernel(
         wh_ref[...],
         b_ref[0, :],
         ts_ref[0, :],
-        dts_ref[0, 0],
+        dts_ref[t, 0],
         flow=flow,
         hidden=hidden,
     )
     h_scr[...] = h_new
-    hs_ref[:, 0, :] = h_new.astype(hs_ref.dtype)
+    hs_ref[...] = h_new.astype(hs_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("flow", "block_b", "interpret"))
@@ -125,26 +129,26 @@ def gru_scan_pallas(
 
     grid = (nb, T)
     kernel = functools.partial(_gru_scan_kernel, flow=flow, hidden=H)
-    out = rt.pallas_call_compat(
+    out = rt.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            ((bb, 1, D), lambda ib, t: (ib, t, 0)),  # xs: stream x_t
+            ((None, bb, D), lambda ib, t: (t, ib, 0)),  # xs: stream x_t (time-major)
             ((bb, H), lambda ib, t: (ib, 0)),  # h0
             ((D, 3 * H), lambda ib, t: (0, 0)),  # wx: resident
             ((H, 3 * H), lambda ib, t: (0, 0)),  # wh: resident
             ((1, 3 * H), lambda ib, t: (0, 0)),  # b
             ((1, H), lambda ib, t: (0, 0)),  # time_scale
-            ((1, 1), lambda ib, t: (t, 0)),  # dt_t
+            rt.smem_spec(),  # dts: per-step scalars
         ],
-        out_specs=((bb, 1, H), lambda ib, t: (ib, t, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, T, H), xs.dtype),
+        out_specs=((None, bb, H), lambda ib, t: (t, ib, 0)),
+        out_shape=jax.ShapeDtypeStruct((T, B, H), xs.dtype),
         scratch_shapes=[((bb, H), jnp.float32)],
         dimension_semantics=(rt.PARALLEL, rt.ARBITRARY),
         interpret=interpret,
         name="gru_scan",
     )(
-        xs,
+        jnp.swapaxes(xs, 0, 1),
         h0,
         wx,
         wh,
@@ -152,7 +156,7 @@ def gru_scan_pallas(
         time_scale.reshape(1, -1),
         dts.reshape(-1, 1),
     )
-    return out
+    return jnp.swapaxes(out, 0, 1)
 
 
 # ---------------------------------------------------------------------------
@@ -226,7 +230,7 @@ def _gru_scan_q_kernel(
         h_scr[...] = h0_ref[...].astype(jnp.float32)
 
     h_new = _gru_q_step_math(
-        xs_ref[:, 0, :].astype(jnp.float32),
+        xs_ref[...].astype(jnp.float32),
         h_scr[...],
         wxq_ref[...],
         whq_ref[...],
@@ -239,7 +243,7 @@ def _gru_scan_q_kernel(
         n_seg=n_seg,
     )
     h_scr[...] = h_new
-    hs_ref[:, 0, :] = h_new.astype(hs_ref.dtype)
+    hs_ref[...] = h_new.astype(hs_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret", "n_seg"))
@@ -264,29 +268,29 @@ def gru_scan_pallas_int8(
     assert B % bb == 0
     nb = B // bb
     kernel = functools.partial(_gru_scan_q_kernel, hidden=H, n_seg=n_seg)
-    return rt.pallas_call_compat(
+    out = rt.pallas_call(
         kernel,
         grid=(nb, T),
         in_specs=[
-            ((bb, 1, D), lambda ib, t: (ib, t, 0)),
+            ((None, bb, D), lambda ib, t: (t, ib, 0)),
             ((bb, H), lambda ib, t: (ib, 0)),
             ((D, 3 * H), lambda ib, t: (0, 0)),
             ((H, 3 * H), lambda ib, t: (0, 0)),
             ((1, 3 * H), lambda ib, t: (0, 0)),
             ((1, 3 * H), lambda ib, t: (0, 0)),
             ((1, 3 * H), lambda ib, t: (0, 0)),
-            ((1, 1), lambda ib, t: (t, 0)),
+            rt.smem_spec(),
             ((2, n_seg), lambda ib, t: (0, 0)),
             ((2, n_seg), lambda ib, t: (0, 0)),
         ],
-        out_specs=((bb, 1, H), lambda ib, t: (ib, t, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, T, H), jnp.float32),
+        out_specs=((None, bb, H), lambda ib, t: (t, ib, 0)),
+        out_shape=jax.ShapeDtypeStruct((T, B, H), jnp.float32),
         scratch_shapes=[((bb, H), jnp.float32)],
         dimension_semantics=(rt.PARALLEL, rt.ARBITRARY),
         interpret=interpret,
         name="gru_scan_int8_pwl",
     )(
-        xs,
+        jnp.swapaxes(xs, 0, 1),
         h0,
         wxq,
         whq,
@@ -297,3 +301,4 @@ def gru_scan_pallas_int8(
         sig_tab,
         tanh_tab,
     )
+    return jnp.swapaxes(out, 0, 1)

@@ -43,8 +43,9 @@ hidden-state tensor that the unfused pipeline materializes between the scan
 and head dispatches simply does not exist.
 
 Grid/layout mirrors kernels/gru_scan: grid = (batch_tiles, T), batch tiles
-outer (PARALLEL), time inner (ARBITRARY); the head fires under
-``pl.when(t == T-1)`` and writes the per-window head output tile.
+outer (PARALLEL), time inner (ARBITRARY), x_t streamed from a time-major
+view with the time dim squeezed and per-step dts in SMEM; the head fires
+under ``pl.when(t == T-1)`` and writes the per-window head output tile.
 """
 
 from __future__ import annotations
@@ -78,13 +79,13 @@ def _head_math(h, w1, b1, w2, b2, act_bits):
 
 def _mr_step_kernel(
     # inputs
-    xs_ref,  # [bb, 1, D]   x_t tile (double-buffered by Mosaic)
+    xs_ref,  # [bb, D]      x_t tile (time-major stream, double-buffered by Mosaic)
     h0_ref,  # [bb, H]
     wx_ref,  # [D, 3H]      VMEM-resident across the whole stage map
     wh_ref,  # [H, 3H]
     b_ref,  # [1, 3H]
     ts_ref,  # [1, H]
-    dts_ref,  # [1, 1]
+    dts_ref,  # SMEM [T, 1] per-step dt
     w1_ref,  # [H, Dh]      head weights, VMEM-resident
     b1_ref,  # [1, Dh]
     w2_ref,  # [Dh, K]
@@ -105,13 +106,13 @@ def _mr_step_kernel(
         h_scr[...] = h0_ref[...].astype(jnp.float32)
 
     h_new = _gru_step_math(
-        xs_ref[:, 0, :],
+        xs_ref[...],
         h_scr[...],
         wx_ref[...],
         wh_ref[...],
         b_ref[0, :],
         ts_ref[0, :],
-        dts_ref[0, 0],
+        dts_ref[t, 0],
         flow=flow,
         hidden=hidden,
     )
@@ -153,17 +154,17 @@ def mr_step_pallas(
     nb = B // bb
 
     kernel = functools.partial(_mr_step_kernel, flow=flow, hidden=H, act_bits=act_bits)
-    return rt.pallas_call_compat(
+    return rt.pallas_call(
         kernel,
         grid=(nb, T),
         in_specs=[
-            ((bb, 1, D), lambda ib, t: (ib, t, 0)),  # xs: stream x_t
+            ((None, bb, D), lambda ib, t: (t, ib, 0)),  # xs: stream x_t (time-major)
             ((bb, H), lambda ib, t: (ib, 0)),  # h0
             ((D, 3 * H), lambda ib, t: (0, 0)),  # wx: resident
             ((H, 3 * H), lambda ib, t: (0, 0)),  # wh: resident
             ((1, 3 * H), lambda ib, t: (0, 0)),  # b
             ((1, H), lambda ib, t: (0, 0)),  # time_scale
-            ((1, 1), lambda ib, t: (t, 0)),  # dt_t
+            rt.smem_spec(),  # dts: per-step scalars
             ((H, Dh), lambda ib, t: (0, 0)),  # head w1: resident
             ((1, Dh), lambda ib, t: (0, 0)),  # head b1
             ((Dh, K), lambda ib, t: (0, 0)),  # head w2: resident
@@ -176,7 +177,7 @@ def mr_step_pallas(
         interpret=interpret,
         name="mr_step_fused",
     )(
-        xs,
+        jnp.swapaxes(xs, 0, 1),
         h0,
         wx,
         wh,
@@ -225,7 +226,7 @@ def _mr_step_q_kernel(
 
     f32 = jnp.float32
     h_new = _gru_q_step_math(
-        xs_ref[:, 0, :].astype(f32),
+        xs_ref[...].astype(f32),
         h_scr[...],
         wxq_ref[...],
         whq_ref[...],
@@ -277,18 +278,18 @@ def mr_step_pallas_int8(
     assert B % bb == 0
     nb = B // bb
     kernel = functools.partial(_mr_step_q_kernel, hidden=H, n_seg=n_seg)
-    return rt.pallas_call_compat(
+    return rt.pallas_call(
         kernel,
         grid=(nb, T),
         in_specs=[
-            ((bb, 1, D), lambda ib, t: (ib, t, 0)),
+            ((None, bb, D), lambda ib, t: (t, ib, 0)),
             ((bb, H), lambda ib, t: (ib, 0)),
             ((D, 3 * H), lambda ib, t: (0, 0)),
             ((H, 3 * H), lambda ib, t: (0, 0)),
             ((1, 3 * H), lambda ib, t: (0, 0)),
             ((1, 3 * H), lambda ib, t: (0, 0)),
             ((1, 3 * H), lambda ib, t: (0, 0)),
-            ((1, 1), lambda ib, t: (t, 0)),
+            rt.smem_spec(),
             ((2, n_seg), lambda ib, t: (0, 0)),
             ((2, n_seg), lambda ib, t: (0, 0)),
             ((H, Dh), lambda ib, t: (0, 0)),
@@ -305,7 +306,7 @@ def mr_step_pallas_int8(
         interpret=interpret,
         name="mr_step_fused_int8_pwl",
     )(
-        xs,
+        jnp.swapaxes(xs, 0, 1),
         h0,
         wxq,
         whq,
@@ -353,7 +354,7 @@ def _ltc_step_math(x, h, w_in, w_rec, bias, a, inv_tau, *, sub_dt: float, n_subs
 
 def _mr_step_ltc_kernel(
     # inputs
-    xs_ref,  # [bb, 1, D]   x_t tile
+    xs_ref,  # [bb, D]      x_t tile
     h0_ref,  # [bb, H]
     w_in_ref,  # [D, H]     VMEM-resident across the whole stage map
     w_rec_ref,  # [H, H]
@@ -380,7 +381,7 @@ def _mr_step_ltc_kernel(
         h_scr[...] = h0_ref[...].astype(jnp.float32)
 
     h_new = _ltc_step_math(
-        xs_ref[:, 0, :],
+        xs_ref[...],
         h_scr[...],
         w_in_ref[...],
         w_rec_ref[...],
@@ -434,11 +435,11 @@ def mr_step_ltc_pallas(
         n_substeps=n_substeps,
         act_bits=act_bits,
     )
-    return rt.pallas_call_compat(
+    return rt.pallas_call(
         kernel,
         grid=(nb, T),
         in_specs=[
-            ((bb, 1, D), lambda ib, t: (ib, t, 0)),  # xs: stream x_t
+            ((None, bb, D), lambda ib, t: (t, ib, 0)),  # xs: stream x_t (time-major)
             ((bb, H), lambda ib, t: (ib, 0)),  # h0
             ((D, H), lambda ib, t: (0, 0)),  # w_in: resident
             ((H, H), lambda ib, t: (0, 0)),  # w_rec: resident
@@ -457,7 +458,7 @@ def mr_step_ltc_pallas(
         interpret=interpret,
         name="mr_step_fused_ltc",
     )(
-        xs,
+        jnp.swapaxes(xs, 0, 1),
         h0,
         w_in,
         w_rec,
@@ -490,7 +491,7 @@ def _node_step_math(x, h, w_f1, b_f1, w_f2, b_f2, w_in, b_in, *, sub_dt: float, 
 
 
 def _mr_step_node_kernel(
-    xs_ref,  # [bb, 1, D]
+    xs_ref,  # [bb, D]
     h0_ref,  # [bb, H]
     w_f1_ref,  # [H, H]     vector-field MLP, VMEM-resident
     b_f1_ref,  # [1, H]
@@ -516,7 +517,7 @@ def _mr_step_node_kernel(
         h_scr[...] = h0_ref[...].astype(jnp.float32)
 
     h_new = _node_step_math(
-        xs_ref[:, 0, :],
+        xs_ref[...],
         h_scr[...],
         w_f1_ref[...],
         b_f1_ref[0, :],
@@ -572,11 +573,11 @@ def mr_step_node_pallas(
         n_substeps=n_substeps,
         act_bits=act_bits,
     )
-    return rt.pallas_call_compat(
+    return rt.pallas_call(
         kernel,
         grid=(nb, T),
         in_specs=[
-            ((bb, 1, D), lambda ib, t: (ib, t, 0)),
+            ((None, bb, D), lambda ib, t: (t, ib, 0)),
             ((bb, H), lambda ib, t: (ib, 0)),
             ((H, H), lambda ib, t: (0, 0)),  # w_f1: resident
             ((1, H), lambda ib, t: (0, 0)),
@@ -596,7 +597,7 @@ def mr_step_node_pallas(
         interpret=interpret,
         name="mr_step_fused_node",
     )(
-        xs,
+        jnp.swapaxes(xs, 0, 1),
         h0,
         w_f1,
         b_f1.reshape(1, -1),
@@ -667,7 +668,7 @@ def _mr_step_ltc_q_kernel(
     w_in = w_inq_ref[...].astype(f32) * w_in_scale_ref[0, :]
     w_rec = w_recq_ref[...].astype(f32) * w_rec_scale_ref[0, :]
     h_new = _ltc_q_step_math(
-        xs_ref[:, 0, :].astype(f32),
+        xs_ref[...].astype(f32),
         h_scr[...],
         w_in,
         w_rec,
@@ -726,11 +727,11 @@ def mr_step_ltc_pallas_int8(
     kernel = functools.partial(
         _mr_step_ltc_q_kernel, sub_dt=dt / n_substeps, n_substeps=n_substeps, n_seg=n_seg
     )
-    return rt.pallas_call_compat(
+    return rt.pallas_call(
         kernel,
         grid=(nb, T),
         in_specs=[
-            ((bb, 1, D), lambda ib, t: (ib, t, 0)),
+            ((None, bb, D), lambda ib, t: (t, ib, 0)),
             ((bb, H), lambda ib, t: (ib, 0)),
             ((D, H), lambda ib, t: (0, 0)),
             ((1, H), lambda ib, t: (0, 0)),
@@ -754,7 +755,7 @@ def mr_step_ltc_pallas_int8(
         interpret=interpret,
         name="mr_step_fused_ltc_int8_pwl",
     )(
-        xs,
+        jnp.swapaxes(xs, 0, 1),
         h0,
         w_inq,
         w_in_scale.reshape(1, -1),

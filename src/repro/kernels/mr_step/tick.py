@@ -55,6 +55,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from repro.core import encoders
 from repro.core.quant import make_sigmoid_table, make_tanh_table, quantize_int8
@@ -81,6 +82,53 @@ def tick_supported(cfg, *, int8: bool = False) -> bool:
 
 
 # ---------------------------------------------------------------------------
+# shared bank body pieces
+# ---------------------------------------------------------------------------
+# Layout: every per-slot operand carries the slot axis first, and the
+# per-slot vectors (stats, biases, scales, Theta, flags) are laid out
+# [S, 1, X], so each block's last two dims are the full array dims for ANY
+# bank size (Mosaic's block rule) and ``ref[s]`` reads one slot's row as a
+# (1, X) tile. Outputs are stored as whole tiles — never scalars.
+def _ingest(buf, new, out):
+    """Ring-buffer roll of the whole bank into ``out``: drop the oldest
+    ``chunk`` rows, append the tick's chunk."""
+    L, chunk = buf.shape[1], new.shape[1]
+    out[:, : L - chunk, :] = buf[:, chunk:, :]
+    out[:, L - chunk :, :] = new[...]
+
+
+def _load_windows(x_scr, buf_y_out, buf_u_out, mean, scale, s, n):
+    """Slot ``s``'s normalized input rows (frozen admission stats) into the
+    [L, D] scratch the window substeps read with strided loads."""
+    x_scr[:, :n] = (buf_y_out[s] - mean[s]) / scale[s]
+    if buf_u_out is not None:
+        x_scr[:, n:] = buf_u_out[s]
+
+
+def _readout(out, theta0, seed, active, theta_out, delta_out, s, *, n_coef, ema):
+    """EMA Theta readout + relative delta (the eviction signal) for slot s."""
+    raw = jnp.mean(out[:, :n_coef], axis=0, keepdims=True)
+    prev = theta0[s]
+    theta = jnp.where(seed[s] > 0, raw, ema * prev + (1.0 - ema) * raw)
+    change = jnp.max(jnp.abs(theta - prev), axis=1, keepdims=True)
+    delta = change / (jnp.max(jnp.abs(theta), axis=1, keepdims=True) + 1e-3)
+    theta_out[s] = theta
+    delta_out[s] = jnp.where(active[s] > 0, delta, jnp.inf)
+
+
+def _bank_specs(bank: int):
+    def blk(*shape):
+        return ((bank, *shape), lambda ib: (ib,) + (0,) * len(shape))
+
+    return blk
+
+
+def _rows(x):
+    """[S, X] -> [S, 1, X]: the per-slot vector layout of the bank blocks."""
+    return x.reshape(x.shape[0], 1, -1)
+
+
+# ---------------------------------------------------------------------------
 # fp32 banked tick kernel
 # ---------------------------------------------------------------------------
 def _mr_tick_kernel(
@@ -102,44 +150,38 @@ def _mr_tick_kernel(
         buf_u, new_u = refs[i], refs[i + 1]
         i += 2
     buf_y_out, theta_out, delta_out = refs[i], refs[i + 1], refs[i + 2]
-    if has_u:
-        buf_u_out = refs[i + 3]
+    buf_u_out = refs[i + 3] if has_u else None
+    x_scr = refs[-1]
 
     # 1. ring-buffer window ingest: roll in-kernel, write the buffer back
-    chunk = new_y.shape[1]
-    rolled_y = jnp.concatenate([buf_y[:, chunk:, :], new_y[...]], axis=1)
-    buf_y_out[...] = rolled_y
+    _ingest(buf_y, new_y, buf_y_out)
     if has_u:
-        rolled_u = jnp.concatenate([buf_u[:, chunk:, :], new_u[...]], axis=1)
-        buf_u_out[...] = rolled_u
+        _ingest(buf_u, new_u, buf_u_out)
 
-    for s in range(bank):  # static unroll: the bank's slots share the VMEM stay
-        xn = (rolled_y[s] - mean[s, :][None, :]) / scale[s, :][None, :]
-        x = jnp.concatenate([xn, rolled_u[s]], axis=-1) if has_u else xn
-        # static window slices of the rolled buffer (data/windows semantics)
-        xs = jnp.stack([x[w * stride : w * stride + window] for w in range(n_windows)])
-        # 2. K unrolled recovery substeps over the VMEM-resident hidden state
+    n = buf_y.shape[-1]
+
+    @pl.loop(0, bank)  # the bank's slots share the VMEM stay, one at a time
+    def _(s):
+        _load_windows(x_scr, buf_y_out, buf_u_out, mean, scale, s, n)
+        # 2. K unrolled recovery substeps over the VMEM-resident hidden state;
+        # step t of every window is one strided row load (data/windows
+        # semantics: window w starts at row w * stride)
         h = jnp.zeros((n_windows, hidden), jnp.float32)
         for t in range(window):
             h = _gru_step_math(
-                xs[:, t, :],
+                x_scr[pl.ds(t, n_windows, stride=stride), :],
                 h,
                 wx[s],
                 wh[s],
-                b[s, :],
-                ts[s, :],
+                b[s, 0],
+                ts[s, 0],
                 jnp.float32(1.0),
                 flow=flow,
                 hidden=hidden,
             )
         # 3. EMA Theta readout + relative delta (the eviction signal)
-        out = _head_math(h, w1[s], b1[s, :], w2[s], b2[s, :], None)
-        raw = jnp.mean(out[:, :n_coef], axis=0)
-        prev = theta0[s, :]
-        theta = jnp.where(seed[s, 0] > 0, raw, ema * prev + (1.0 - ema) * raw)
-        delta = jnp.max(jnp.abs(theta - prev)) / (jnp.max(jnp.abs(theta)) + 1e-3)
-        theta_out[s, :] = theta
-        delta_out[s, 0] = jnp.where(active[s, 0] > 0, delta, jnp.inf)
+        out = _head_math(h, w1[s], b1[s, 0], w2[s], b2[s, 0], None)
+        _readout(out, theta0, seed, active, theta_out, delta_out, s, n_coef=n_coef, ema=ema)
 
 
 @functools.partial(
@@ -183,34 +225,33 @@ def mr_tick_pallas(
     bank = slots_per_bank
     assert S % bank == 0, f"{S} slots not divisible by slots_per_bank {bank}"
     has_u = buf_u is not None
-
-    def blk(*shape):
-        return ((bank, *shape), lambda ib: (ib,) + (0,) * len(shape))
+    blk = _bank_specs(bank)
 
     in_specs = [
         blk(L, n),  # buf_y: streamed per bank (Mosaic ping-pongs the DMA)
         blk(C, n),  # new_y
-        blk(n),  # mean
-        blk(n),  # scale
-        blk(Kc),  # theta0
-        blk(1),  # seed
-        blk(1),  # active
+        blk(1, n),  # mean
+        blk(1, n),  # scale
+        blk(1, Kc),  # theta0
+        blk(1, 1),  # seed
+        blk(1, 1),  # active
         blk(D, 3 * H),  # wx: the bank's slots resident together
         blk(H, 3 * H),  # wh
-        blk(3 * H),  # b
-        blk(H),  # time_scale
+        blk(1, 3 * H),  # b
+        blk(1, H),  # time_scale
         blk(H, Dh),  # head w1
-        blk(Dh),  # head b1
+        blk(1, Dh),  # head b1
         blk(Dh, Ko),  # head w2
-        blk(Ko),  # head b2
+        blk(1, Ko),  # head b2
     ]
-    operands = [buf_y, new_y, mean, scale, theta0, seed, active, wx, wh, b, time_scale]
-    operands += [w1, b1, w2, b2]
-    out_specs = [blk(L, n), blk(Kc), blk(1)]
+    operands = [buf_y, new_y, _rows(mean), _rows(scale), _rows(theta0), _rows(seed)]
+    operands += [_rows(active), wx, wh, _rows(b), _rows(time_scale)]
+    operands += [w1, _rows(b1), w2, _rows(b2)]
+    out_specs = [blk(L, n), blk(1, Kc), blk(1, 1)]
     out_shape = [
         jax.ShapeDtypeStruct((S, L, n), jnp.float32),
-        jax.ShapeDtypeStruct((S, Kc), jnp.float32),
-        jax.ShapeDtypeStruct((S, 1), jnp.float32),
+        jax.ShapeDtypeStruct((S, 1, Kc), jnp.float32),
+        jax.ShapeDtypeStruct((S, 1, 1), jnp.float32),
     ]
     if has_u:
         m = buf_u.shape[-1]
@@ -231,16 +272,18 @@ def mr_tick_pallas(
         ema=ema,
         has_u=has_u,
     )
-    return rt.pallas_call_compat(
+    out = rt.pallas_call(
         kernel,
         grid=(S // bank,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=[((L, D), jnp.float32)],
         dimension_semantics=(rt.PARALLEL,),
         interpret=interpret,
         name="mr_tick_banked",
     )(*operands)
+    return (out[0], out[1].reshape(S, Kc), out[2].reshape(S, 1), *out[3:])
 
 
 # ---------------------------------------------------------------------------
@@ -266,45 +309,38 @@ def _mr_tick_q_kernel(
         buf_u, new_u = refs[i], refs[i + 1]
         i += 2
     buf_y_out, theta_out, delta_out = refs[i], refs[i + 1], refs[i + 2]
-    if has_u:
-        buf_u_out = refs[i + 3]
+    buf_u_out = refs[i + 3] if has_u else None
+    x_scr = refs[-1]
 
-    chunk = new_y.shape[1]
-    rolled_y = jnp.concatenate([buf_y[:, chunk:, :], new_y[...]], axis=1)
-    buf_y_out[...] = rolled_y
+    _ingest(buf_y, new_y, buf_y_out)
     if has_u:
-        rolled_u = jnp.concatenate([buf_u[:, chunk:, :], new_u[...]], axis=1)
-        buf_u_out[...] = rolled_u
+        _ingest(buf_u, new_u, buf_u_out)
 
     f32 = jnp.float32
-    for s in range(bank):
-        xn = (rolled_y[s] - mean[s, :][None, :]) / scale[s, :][None, :]
-        x = jnp.concatenate([xn, rolled_u[s]], axis=-1) if has_u else xn
-        xs = jnp.stack([x[w * stride : w * stride + window] for w in range(n_windows)])
+    n = buf_y.shape[-1]
+
+    @pl.loop(0, bank)
+    def _(s):
+        _load_windows(x_scr, buf_y_out, buf_u_out, mean, scale, s, n)
         h = jnp.zeros((n_windows, hidden), f32)
         for t in range(window):
             h = _gru_q_step_math(
-                xs[:, t, :].astype(f32),
+                x_scr[pl.ds(t, n_windows, stride=stride), :],
                 h,
                 wxq[s],
                 whq[s],
-                wx_scale[s, :],
-                wh_scale[s, :],
-                b[s, :],
+                wx_scale[s, 0],
+                wh_scale[s, 0],
+                b[s, 0],
                 sig_tab[...],
                 tanh_tab[...],
                 hidden=hidden,
                 n_seg=n_seg,
             )
-        w1 = w1q[s].astype(f32) * w1_scale[s, :]
-        w2 = w2q[s].astype(f32) * w2_scale[s, :]
-        out = _head_math(h, w1, b1[s, :], w2, b2[s, :], None)
-        raw = jnp.mean(out[:, :n_coef], axis=0)
-        prev = theta0[s, :]
-        theta = jnp.where(seed[s, 0] > 0, raw, ema * prev + (1.0 - ema) * raw)
-        delta = jnp.max(jnp.abs(theta - prev)) / (jnp.max(jnp.abs(theta)) + 1e-3)
-        theta_out[s, :] = theta
-        delta_out[s, 0] = jnp.where(active[s, 0] > 0, delta, jnp.inf)
+        w1 = w1q[s].astype(f32) * w1_scale[s, 0]
+        w2 = w2q[s].astype(f32) * w2_scale[s, 0]
+        out = _head_math(h, w1, b1[s, 0], w2, b2[s, 0], None)
+        _readout(out, theta0, seed, active, theta_out, delta_out, s, n_coef=n_coef, ema=ema)
 
 
 @functools.partial(
@@ -352,22 +388,21 @@ def mr_tick_pallas_int8(
     bank = slots_per_bank
     assert S % bank == 0, f"{S} slots not divisible by slots_per_bank {bank}"
     has_u = buf_u is not None
-
-    def blk(*shape):
-        return ((bank, *shape), lambda ib: (ib,) + (0,) * len(shape))
+    blk = _bank_specs(bank)
 
     tab = ((2, n_seg), lambda ib: (0, 0))
-    in_specs = [blk(L, n), blk(C, n), blk(n), blk(n), blk(Kc), blk(1), blk(1)]
-    in_specs += [blk(D, 3 * H), blk(H, 3 * H), blk(3 * H), blk(3 * H), blk(3 * H), tab, tab]
-    in_specs += [blk(H, Dh), blk(Dh), blk(Dh), blk(Dh, Ko), blk(Ko), blk(Ko)]
-    operands = [buf_y, new_y, mean, scale, theta0, seed, active]
-    operands += [wxq, whq, wx_scale, wh_scale, b, sig_tab, tanh_tab]
-    operands += [w1q, w1_scale, b1, w2q, w2_scale, b2]
-    out_specs = [blk(L, n), blk(Kc), blk(1)]
+    in_specs = [blk(L, n), blk(C, n), blk(1, n), blk(1, n), blk(1, Kc), blk(1, 1), blk(1, 1)]
+    in_specs += [blk(D, 3 * H), blk(H, 3 * H), blk(1, 3 * H), blk(1, 3 * H), blk(1, 3 * H)]
+    in_specs += [tab, tab, blk(H, Dh), blk(1, Dh), blk(1, Dh), blk(Dh, Ko), blk(1, Ko), blk(1, Ko)]
+    operands = [buf_y, new_y, _rows(mean), _rows(scale), _rows(theta0), _rows(seed)]
+    operands += [_rows(active), wxq, whq, _rows(wx_scale), _rows(wh_scale), _rows(b)]
+    operands += [sig_tab, tanh_tab, w1q, _rows(w1_scale), _rows(b1), w2q, _rows(w2_scale)]
+    operands += [_rows(b2)]
+    out_specs = [blk(L, n), blk(1, Kc), blk(1, 1)]
     out_shape = [
         jax.ShapeDtypeStruct((S, L, n), jnp.float32),
-        jax.ShapeDtypeStruct((S, Kc), jnp.float32),
-        jax.ShapeDtypeStruct((S, 1), jnp.float32),
+        jax.ShapeDtypeStruct((S, 1, Kc), jnp.float32),
+        jax.ShapeDtypeStruct((S, 1, 1), jnp.float32),
     ]
     if has_u:
         m = buf_u.shape[-1]
@@ -388,16 +423,18 @@ def mr_tick_pallas_int8(
         n_seg=n_seg,
         has_u=has_u,
     )
-    return rt.pallas_call_compat(
+    out = rt.pallas_call(
         kernel,
         grid=(S // bank,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=[((L, D), jnp.float32)],
         dimension_semantics=(rt.PARALLEL,),
         interpret=interpret,
         name="mr_tick_banked_int8_pwl",
     )(*operands)
+    return (out[0], out[1].reshape(S, Kc), out[2].reshape(S, 1), *out[3:])
 
 
 # ---------------------------------------------------------------------------

@@ -39,8 +39,10 @@ VMEM_BUDGET_FRACTION = 0.5
 
 # device_kind substring -> VMEM bytes/core (first match wins, checked in
 # order). Every currently-shipping TPU core carries 16 MiB of VMEM except
-# Trillium-class parts; unknown kinds (CPU hosts, GPUs) fall back to the
-# v4/v5 figure so CPU CI resolves the same budget a v5e deployment would.
+# Trillium-class parts. A TPU whose kind is missing here is an error (its
+# budget is unknown, so any tile chosen for it would be a guess); non-TPU
+# hosts (CPU tests) fall back to the v4/v5 figure so they resolve the same
+# budget a v5e deployment would.
 PLATFORM_VMEM_BYTES: tuple[tuple[str, int], ...] = (
     ("v6", 32 * 1024 * 1024),  # Trillium
     ("v5", VMEM_BYTES_PER_CORE),
@@ -53,10 +55,11 @@ def resolve_vmem_budget(device=None, *, fraction: float = VMEM_BUDGET_FRACTION) 
 
     Resolution order: ``device.memory_stats()``'s VMEM figure when the
     runtime exposes one (source ``"memory_stats"``), else the platform table
-    keyed on ``device_kind`` (source ``"platform:<key>"``), else the v4/v5
-    default (source ``"default"``). The result is ``fraction`` of the raw
-    size (headroom for Mosaic double-buffering). Deterministic on CPU: no
-    entry matches, so the default applies. The source string lands in
+    keyed on ``device_kind`` (source ``"platform:<key>"``), else — off TPU
+    only — the v4/v5 default (source ``"default"``); a TPU kind missing from
+    the table raises. The result is ``fraction`` of the raw size (headroom
+    for Mosaic double-buffering). Deterministic on CPU: no entry matches, so
+    the default applies. The source string lands in
     ``plan.lowering.vmem_budget_source`` so an R2 residency finding is
     attributable to the budget that produced the tile.
     """
@@ -83,6 +86,11 @@ def resolve_vmem_budget(device=None, *, fraction: float = VMEM_BUDGET_FRACTION) 
                     size, source = nbytes, f"platform:{key}"
                     break
     if size is None:
+        if getattr(device, "platform", None) == "tpu":
+            raise ValueError(
+                f"no VMEM figure for TPU device_kind {device.device_kind!r}: "
+                "add it to tiling.PLATFORM_VMEM_BYTES"
+            )
         size = VMEM_BYTES_PER_CORE
     return int(size * fraction), source
 
@@ -99,7 +107,7 @@ def detect_vmem_budget(device=None, *, fraction: float = VMEM_BUDGET_FRACTION) -
 # the kernel holds them resident, and the NODE field does two H x H mats per
 # Euler substep — so they catch an order-of-magnitude model drift (a new
 # resident buffer the model misses, a dropped term) without flaking on
-# backend lowering details. Measured per-step ratios on CPU jax 0.4.37:
+# backend lowering details. Measured per-step ratios on the CPU lowering:
 # gru 1.40, ltc 1.34, node 3.25.
 RESIDENCY_BANDS: dict[str, tuple[float, float]] = {
     "gru": (0.25, 8.0),
@@ -349,7 +357,7 @@ def auto_block_b(
 # traffic of the compiled serve tick vs tick_vmem_bytes with every local
 # slot resident (the CPU lowering re-streams the whole working set per scan
 # trip). Wide for the same reason as RESIDENCY_BANDS; measured per-step
-# ratios on CPU jax 0.4.37 (tiny audit-matrix shapes): 0.97 fp32 gru,
+# ratios on the CPU lowering (tiny audit-matrix shapes): 0.97 fp32 gru,
 # 1.87 int8/PWL (dequant widens the parsed traffic vs the s8 residency).
 TICK_RESIDENCY_BAND: tuple[float, float] = (0.25, 8.0)
 
@@ -386,6 +394,51 @@ def tick_vmem_bytes(cfg, scfg, *, slots_per_bank: int = 1, int8: bool = False, n
     return vm
 
 
+def _vmem_block_bytes(rows: int, cols: int, itemsize: int = 4) -> int:
+    """One ``[rows, cols]`` block as Mosaic lays it out in VMEM: columns pad
+    to 128 lanes; multi-row blocks pad rows to the sublane tile (8 rows of
+    32-bit values, 32 of int8), single-row blocks take one row."""
+    sub = 1 if rows == 1 else 8 * 4 // itemsize
+    return -(-rows // sub) * sub * -(-cols // 128) * 128 * itemsize
+
+
+def tick_vmem_footprint(
+    cfg, scfg, *, slots_per_bank: int = 1, int8: bool = False, n_seg: int = 16
+) -> int:
+    """VMEM one ``mr_tick`` grid step allocates: what must fit the budget.
+
+    :func:`tick_vmem_bytes` counts the bank's logical residency (the figure
+    audit rule R2 holds the compiled traffic to). The kernel's VMEM is
+    larger: every per-slot block of tick.py's BlockSpecs is padded to
+    Mosaic's tiles — a ``[L, 3]`` ring buffer occupies ``[L, 128]`` lanes —
+    and Mosaic double-buffers every streamed input and output block so bank
+    ``i + 1`` moves while bank ``i`` computes. This model counts both, plus
+    the window scratch and the shared PWL tables. For the deployment-width
+    fleet of ``chip_smoke.py`` it reckons 1.64 MB per slot where the v5e
+    compiler reported 21.6 MB scoped VMEM for a 16-slot bank (1.35 MB per
+    slot): conservative, so a bank it admits fits.
+    """
+    n, m = cfg.state_dim, cfg.input_dim
+    D, H, Dh = n + m, cfg.hidden, cfg.dense_hidden
+    Ko = cfg.n_coef + cfg.n_shifts
+    L, C = scfg.buf_len, scfg.chunk
+    w = 1 if int8 else 4
+    blocks = [(L, n), (C, n), (1, n), (1, n), (1, cfg.n_coef), (1, 1), (1, 1)]
+    blocks += [(D, 3 * H, w), (H, 3 * H, w), (H, Dh, w), (Dh, Ko, w)]  # weights
+    if int8:  # scale + bias rows: gates, head layer 1, head layer 2
+        blocks += [(1, 3 * H)] * 3 + [(1, Dh)] * 2 + [(1, Ko)] * 2
+    else:  # bias + time-scale rows, head biases
+        blocks += [(1, 3 * H), (1, H), (1, Dh), (1, Ko)]
+    blocks += [(L, n), (1, cfg.n_coef), (1, 1)]  # outputs: buffer, Theta, delta
+    if m:
+        blocks += [(L, m), (C, m), (L, m)]  # input ring in + chunk + rolled out
+    per_slot = 2 * sum(_vmem_block_bytes(*b) for b in blocks)  # double-buffered
+    vm = slots_per_bank * per_slot + _vmem_block_bytes(L, D)  # + window scratch
+    if int8:
+        vm += 2 * 2 * _vmem_block_bytes(2, n_seg)  # sigmoid/tanh PWL tables
+    return vm
+
+
 def slots_per_bank_candidates(n_slots: int) -> list[int]:
     """Every legal bank size for ``n_slots``, largest residency first.
 
@@ -401,11 +454,12 @@ def slots_per_bank_candidates(n_slots: int) -> list[int]:
 def auto_slots_per_bank(
     cfg, scfg, n_slots: int, vmem_budget_bytes: int | None, *, int8: bool = False
 ) -> int:
-    """Largest divisor of ``n_slots`` whose banked-tick residency fits.
+    """Largest divisor of ``n_slots`` whose banked-tick VMEM footprint fits.
 
     Walks :func:`slots_per_bank_candidates` from largest (all slots in one
-    bank — no grid streaming at all) down to 1; returns 0 when even a single
-    slot's working set exceeds the budget — the caller (``compile_plan``
+    bank — no grid streaming at all) down to 1, against
+    :func:`tick_vmem_footprint`; returns 0 when even a single slot's blocks
+    exceed the budget — the caller (``compile_plan``
     resolving ``tick_kernel="auto"``) falls back to the composite tick then.
     With no budget configured the full slot set is one bank, mirroring
     auto_block_b.
@@ -415,6 +469,6 @@ def auto_slots_per_bank(
     if vmem_budget_bytes is None:
         return n_slots
     for bank in slots_per_bank_candidates(n_slots):
-        if tick_vmem_bytes(cfg, scfg, slots_per_bank=bank, int8=int8) <= vmem_budget_bytes:
+        if tick_vmem_footprint(cfg, scfg, slots_per_bank=bank, int8=int8) <= vmem_budget_bytes:
             return bank
     return 0

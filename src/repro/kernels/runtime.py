@@ -1,25 +1,12 @@
-"""Kernel runtime: Pallas API-drift shims + the shared dispatch decision.
+"""Kernel runtime: the one ``pallas_call`` constructor + the dispatch policy.
 
-Every kernel family (gru_scan, flash_attention, ssd_scan) goes through this
-module instead of touching ``pl.pallas_call`` directly. It owns the three
-places where the Pallas TPU API has drifted across JAX releases, plus the
-TPU/interpret/reference dispatch policy that used to be copy-pasted into all
-three ``ops.py`` files:
-
-1. Compiler params class name.  ``pltpu.TPUCompilerParams`` (JAX <= 0.4.x)
-   was renamed to ``pltpu.CompilerParams`` (JAX >= 0.5).  ``compiler_params``
-   resolves whichever spelling the installed JAX exposes.
-2. BlockSpec argument order.  Old JAX took ``BlockSpec(index_map,
-   block_shape)``; modern JAX takes ``BlockSpec(block_shape, index_map)``.
-   ``block_spec`` inspects the installed signature once and builds specs in
-   the right order.
-3. VMEM scratch spelling.  ``vmem_scratch`` wraps ``pltpu.VMEM(shape,
-   dtype)`` (raising a clear error if a future release moves it again).
-
-``pallas_call_compat`` is the single entry point: kernels hand it the kernel
-body, grid, (block_shape, index_map) spec pairs, output shapes, scratch
-shapes and dimension semantics, and it assembles a version-correct
-``pl.pallas_call``.
+Every kernel family (gru_scan, mr_step, flash_attention, ssd_scan) goes
+through this module instead of touching ``pl.pallas_call`` directly.
+``pallas_call`` takes the kernel body, grid, (block_shape, index_map) spec
+pairs, output shapes, scratch shapes and dimension semantics, and assembles
+the ``pl.pallas_call`` with ``pltpu.CompilerParams``. ``smem_spec`` places a
+whole small operand (per-step scalars such as ``dts``) in SMEM, where the
+kernel reads it as scalars instead of through a ``(1, 1)`` VMEM block.
 
 ``resolve_dispatch`` centralizes the backend decision: the compiled kernel on
 TPU, the kernel body under the Pallas interpreter when explicitly requested
@@ -29,71 +16,31 @@ TPU, the kernel body under the Pallas interpreter when explicitly requested
 from __future__ import annotations
 
 import enum
-import inspect
 from typing import Any, Callable, Sequence
 
 import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Grid-dimension semantics: plain strings on every JAX we support; prefer the
-# module constants when present so we track any future enum migration.
-PARALLEL = getattr(pltpu, "PARALLEL", "parallel")
-ARBITRARY = getattr(pltpu, "ARBITRARY", "arbitrary")
-
-_COMPILER_PARAMS_SPELLINGS = ("CompilerParams", "TPUCompilerParams")
+PARALLEL = pltpu.PARALLEL
+ARBITRARY = pltpu.ARBITRARY
 
 
-def resolve_compiler_params_cls(ns: Any = pltpu) -> type:
-    """The compiler-params class under whichever name ``ns`` exposes it.
-
-    ``ns`` is injectable so the regression tests can pin the resolution
-    against namespaces carrying only one of the two historical spellings.
-    """
-    for name in _COMPILER_PARAMS_SPELLINGS:
-        cls = getattr(ns, name, None)
-        if cls is not None:
-            return cls
-    raise AttributeError(
-        f"Pallas TPU module {ns!r} exposes none of {_COMPILER_PARAMS_SPELLINGS}; "
-        "unsupported JAX version — extend kernels/runtime.py"
-    )
-
-
-def compiler_params(dimension_semantics: Sequence[str] | None = None, ns: Any = pltpu, **kw) -> Any:
-    """Version-correct compiler-params object (CompilerParams/TPUCompilerParams)."""
+def compiler_params(dimension_semantics: Sequence[Any] | None = None, **kw) -> Any:
+    """``pltpu.CompilerParams`` with the grid's dimension semantics."""
     if dimension_semantics is not None:
         kw["dimension_semantics"] = tuple(dimension_semantics)
-    return resolve_compiler_params_cls(ns)(**kw)
+    return pltpu.CompilerParams(**kw)
 
 
-def blockspec_block_shape_first(cls: type = pl.BlockSpec) -> bool:
-    """True when ``cls(block_shape, index_map)`` is the installed order."""
-    try:
-        params = [p for p in inspect.signature(cls.__init__).parameters if p != "self"]
-    except (TypeError, ValueError):  # C-accelerated/builtin signature
-        return True
-    return not (params and params[0] == "index_map")
+def block_spec(block_shape: tuple, index_map: Callable | None = None) -> pl.BlockSpec:
+    """BlockSpec from a (block_shape, index_map) pair (``None`` dims squeeze)."""
+    return pl.BlockSpec(tuple(block_shape), index_map)
 
 
-_BLOCK_SHAPE_FIRST = blockspec_block_shape_first()
-
-
-def block_spec(block_shape: tuple[int, ...], index_map: Callable | None = None) -> pl.BlockSpec:
-    """BlockSpec with the argument order the installed JAX expects."""
-    if _BLOCK_SHAPE_FIRST:
-        return pl.BlockSpec(tuple(block_shape), index_map)
-    return pl.BlockSpec(index_map, tuple(block_shape))
-
-
-def vmem_scratch(shape: tuple[int, ...], dtype) -> Any:
-    """VMEM scratch allocation (f32 accumulators, resident state, ...)."""
-    vmem = getattr(pltpu, "VMEM", None)
-    if vmem is None:
-        raise AttributeError(
-            "pltpu.VMEM missing; unsupported JAX version — extend kernels/runtime.py"
-        )
-    return vmem(tuple(shape), dtype)
+def smem_spec() -> pl.BlockSpec:
+    """The whole operand resident in SMEM (scalar reads inside the kernel)."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 class Dispatch(enum.Enum):
@@ -132,27 +79,26 @@ def resolve_dispatch(
     return Dispatch.REFERENCE
 
 
-def pallas_call_compat(
+def pallas_call(
     kernel: Callable,
     *,
     grid: tuple[int, ...],
-    in_specs: Sequence[tuple[tuple[int, ...], Callable | None]],
+    in_specs: Sequence[Any],
     out_specs,
     out_shape,
     scratch_shapes: Sequence[Any] = (),
-    dimension_semantics: Sequence[str] | None = None,
+    dimension_semantics: Sequence[Any] | None = None,
     interpret: bool = False,
     name: str | None = None,
     **compiler_kw,
 ):
     """The one ``pl.pallas_call`` constructor for every kernel family.
 
-    ``in_specs``/``out_specs`` are (block_shape, index_map) pairs — this
-    module turns them into BlockSpecs in the installed argument order.
-    Convention: a single-output kernel passes ``out_specs`` as ONE tuple pair;
-    a multi-output kernel passes a LIST of pairs (mirroring ``out_shape``).
-    ``scratch_shapes`` entries may be (shape, dtype) pairs (VMEM implied) or
-    prebuilt scratch objects.
+    ``in_specs``/``out_specs`` are (block_shape, index_map) pairs or prebuilt
+    BlockSpecs (``smem_spec()``). Convention: a single-output kernel passes
+    ``out_specs`` as ONE pair; a multi-output kernel passes a LIST of pairs
+    (mirroring ``out_shape``). ``scratch_shapes`` entries may be (shape,
+    dtype) pairs (VMEM implied) or prebuilt scratch objects.
     """
 
     def to_spec(s):
@@ -162,7 +108,7 @@ def pallas_call_compat(
 
     def to_scratch(s):
         if isinstance(s, tuple) and len(s) == 2 and isinstance(s[0], tuple):
-            return vmem_scratch(s[0], s[1])
+            return pltpu.VMEM(tuple(s[0]), s[1])
         return s
 
     if isinstance(out_specs, list):

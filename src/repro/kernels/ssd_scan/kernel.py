@@ -106,7 +106,7 @@ def ssd_scan_pallas(
     kernel = functools.partial(_ssd_chunk_kernel, chunk=chunk)
 
     grid = (B, H, nc)
-    y, s_final = rt.pallas_call_compat(
+    y, s_final = rt.pallas_call(
         kernel,
         grid=grid,
         in_specs=[

@@ -136,8 +136,6 @@ def run_cell(
 
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):  # jax <= 0.4.x wraps it in a list
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
         # trip-count-aware HLO analysis (analysis/hlo.py) — raw cost_analysis()
         # counts scan bodies once, under-reporting L-layer models by ~L x.

@@ -8,24 +8,10 @@ everything else sees the real device count.
 
 from __future__ import annotations
 
-import jax
+from repro.parallel import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Elastic variant: any (pods, data, model) whose product <= devices.
-
-    Used by the fault-tolerance runtime to re-mesh onto the surviving device
-    set after a failure (runtime/elastic.py).
-    """
-    return jax.make_mesh(shape, axes)
-
-
-def make_test_mesh(data: int = 2, model: int = 4):
-    """Small mesh for CPU tests (requires >= data*model host devices)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh(shape, axes)

@@ -96,7 +96,7 @@ def build_stream_fleet(
     (n_state, n_input, order)). Each stream gets its own noise seed, so two
     streams of the same system are distinct tenants.
     """
-    from repro.data.dynamics import generate_trajectory, get_system
+    from repro.data.dynamics import add_sensor_noise, generate_trajectory, get_system
 
     specs = [get_system(n) for n in names]
     dts = {s.dt for s in specs}
@@ -105,12 +105,14 @@ def build_stream_fleet(
     n_max = max(s.state_dim for s in specs)
     m_max = max(s.input_dim for s in specs)
     order = max(s.order for s in specs)
+    # one integration per system: tenants of a system share the clean
+    # trajectory and differ in their sensor-noise draw
+    clean = {s.name: generate_trajectory(s.name, n_samples=n_samples)[1:] for s in specs}
     stream_specs, ys_all, us_all = [], [], []
     for i in range(n_streams):
         spec = specs[i % len(specs)]
-        _, ys, us = generate_trajectory(
-            spec.name, n_samples=n_samples, noise_std=noise, seed=seed + i
-        )
+        ys, us = clean[spec.name]
+        ys = add_sensor_noise(ys, noise, seed + i)
         ys = np.pad(ys, ((0, 0), (0, n_max - spec.state_dim)))
         us = np.pad(us, ((0, 0), (0, m_max - us.shape[-1]))) if m_max else np.zeros((len(ys), 0))
         stream_specs.append(spec)
@@ -307,8 +309,57 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main() -> int:
-    args = build_parser().parse_args()
+def service_spec(args, dims, dt: float, *, ckpt_dir=None, ckpt_period: int = 0):
+    """The stream-mode RecoverySpec the parsed flags describe, for a fleet
+    of library shape ``dims = (n_state, n_input, order)`` sampled at ``dt``."""
+    from repro import api
+    from repro.core.stream import StreamConfig
+
+    n_state, n_input, order = dims
+    scfg = StreamConfig(
+        buf_len=args.buf_len,
+        window=args.window,
+        stride=args.stride,
+        chunk=args.chunk,
+        steps_per_tick=args.steps_per_tick,
+        lr=args.lr,
+        delta_tol=args.delta_tol,
+        min_steps=args.min_steps,
+        max_steps=args.max_steps,
+    )
+    return api.RecoverySpec(
+        state_dim=n_state,
+        input_dim=n_input,
+        order=order,
+        hidden=args.hidden,
+        dense_hidden=2 * args.hidden,
+        dt=dt,
+        encoder=args.encoder,
+        precision="int8_pwl" if args.quant else "fp32",
+        fused=args.fused,
+        mode="stream",
+        lr=args.lr,
+        seed=args.seed,
+        n_slots=args.slots,
+        stream=scfg,
+        # the loose tick flags are a thin mapping onto TickSpec: geometry
+        # (steps_per_tick/ema) mirrors the StreamConfig above, the kernel
+        # choice is the only new degree of freedom
+        tick=api.TickSpec(
+            steps_per_tick=args.steps_per_tick,
+            tick_kernel=args.tick_kernel,
+            control=args.control,
+            queue_capacity=args.queue_capacity or max(args.streams, 1),
+            snapshot_period=args.snapshot_period,
+            checkpoint_period=ckpt_period,
+            checkpoint_dir=ckpt_dir,
+        ),
+        mesh_slots=args.mesh,
+    )
+
+
+def main(argv: list[str] | None = None) -> int:
+    args = build_parser().parse_args(argv)
     if args.virtual_devices:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.virtual_devices} "
@@ -337,8 +388,23 @@ def main() -> int:
         )
 
     # jax loads HERE, after the virtual-device environment is pinned
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    # the recovery math is float32: on TPU, XLA's default multiplies float32
+    # matrices in one bfloat16 pass, which moves a stream's convergence far
+    # enough to fail the baseline check below
+    with jax.default_matmul_precision("float32"):
+        return _serve(args)
+
+
+def _serve(args) -> int:
+    """Serve the fleet the flags describe, then hold every recovered stream
+    to the one-shot batch baseline; 0 when all are within tolerance."""
     from repro import api
-    from repro.core.stream import RecoveryService, StreamConfig
+    from repro.core.stream import RecoveryService
     from repro.data.dynamics import embed_true_coef
 
     names = [s.strip() for s in args.systems.split(",") if s.strip()]
@@ -346,17 +412,6 @@ def main() -> int:
     n_samples = args.buf_len + args.chunk * (args.max_steps // args.steps_per_tick + 2)
     specs, ys, us, (n_state, n_input, order) = build_stream_fleet(
         names, args.streams, n_samples, noise=args.noise, seed=args.seed
-    )
-    scfg = StreamConfig(
-        buf_len=args.buf_len,
-        window=args.window,
-        stride=args.stride,
-        chunk=args.chunk,
-        steps_per_tick=args.steps_per_tick,
-        lr=args.lr,
-        delta_tol=args.delta_tol,
-        min_steps=args.min_steps,
-        max_steps=args.max_steps,
     )
     ckpt_dir, ckpt_period = args.checkpoint_dir, args.checkpoint_period
     if args.chaos_kill_shard >= 0:
@@ -366,35 +421,10 @@ def main() -> int:
 
         ckpt_dir = ckpt_dir or tempfile.mkdtemp(prefix="serve_mr_ckpt_")
         ckpt_period = ckpt_period or 2
-    spec = api.RecoverySpec(
-        state_dim=n_state,
-        input_dim=n_input,
-        order=order,
-        hidden=args.hidden,
-        dense_hidden=2 * args.hidden,
-        dt=specs[0].dt,
-        encoder=args.encoder,
-        precision="int8_pwl" if args.quant else "fp32",
-        fused=args.fused,
-        mode="stream",
-        lr=args.lr,
-        seed=args.seed,
-        n_slots=args.slots,
-        stream=scfg,
-        # the loose tick flags are a thin mapping onto TickSpec: geometry
-        # (steps_per_tick/ema) mirrors the StreamConfig above, the kernel
-        # choice is the only new degree of freedom
-        tick=api.TickSpec(
-            steps_per_tick=args.steps_per_tick,
-            tick_kernel=args.tick_kernel,
-            control=args.control,
-            queue_capacity=args.queue_capacity or max(args.streams, 1),
-            snapshot_period=args.snapshot_period,
-            checkpoint_period=ckpt_period,
-            checkpoint_dir=ckpt_dir,
-        ),
-        mesh_slots=args.mesh,
+    spec = service_spec(
+        args, (n_state, n_input, order), specs[0].dt, ckpt_dir=ckpt_dir, ckpt_period=ckpt_period
     )
+    scfg = spec.stream
     supervisor = None
     if args.chaos_kill_shard >= 0:
         from repro.runtime import ServiceSupervisor, kill_shard_once

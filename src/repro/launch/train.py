@@ -32,6 +32,8 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def run_recover(systems: list[str], steps: int, lr: float) -> int:
     """Streaming-recovery driver: one vmapped scan-jitted program recovers
@@ -85,10 +87,14 @@ def main() -> int:
     ap.add_argument("--rules", default="default",
                     help="sharding rules variant (parallel/rules.RULE_VARIANTS)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.recover:
         systems = [s.strip() for s in args.recover.split(",") if s.strip()]
-        return run_recover(systems, args.steps, args.lr if args.lr is not None else 3e-3)
+        # float32 recovery math, as serve_mr runs it (TPU's default would
+        # multiply float32 matrices in one bfloat16 pass)
+        with jax.default_matmul_precision("float32"):
+            return run_recover(systems, args.steps, args.lr if args.lr is not None else 3e-3)
 
     logging.basicConfig(level=logging.INFO, format="%(name)s %(message)s")
     from repro.configs.base import ShapeConfig, get_config

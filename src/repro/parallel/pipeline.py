@@ -30,28 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6 moved shard_map out of experimental
-    from jax import shard_map as _shard_map_mod  # type: ignore
-
-    _shard_map = (
-        _shard_map_mod.shard_map if hasattr(_shard_map_mod, "shard_map") else _shard_map_mod
-    )
-except Exception:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-
-import inspect as _inspect
-
-# the replication-check kwarg was renamed check_rep -> check_vma in jax 0.7
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_replication: bool = True):
-    kw = {_CHECK_KW: check_replication}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+from repro.parallel.rules import make_mesh
 
 
 def split_stages(stacked, n_stages: int):
@@ -124,13 +103,13 @@ def pipeline_spmd(layer_fn, stacked, x_mb: jnp.ndarray, mesh: Mesh, axis: str = 
         jax.tree.map(lambda _: P(axis), staged),
         P(),  # microbatches replicated across stages
     )
-    fn = shard_map(per_stage, mesh=mesh, in_specs=in_specs, out_specs=P(), check_replication=False)
+    fn = jax.shard_map(per_stage, mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False)
     return fn(staged, x_mb)
 
 
 def make_pp_mesh(n_stages: int = 4, data: int = 1):
     """(stage, data) mesh for the pipeline execution mode."""
-    return jax.make_mesh((n_stages, data), ("stage", "data"))
+    return make_mesh((n_stages, data), ("stage", "data"))
 
 
 def bubble_fraction(n_stages: int, n_microbatches: int) -> float:

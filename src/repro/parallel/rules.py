@@ -27,6 +27,7 @@ import threading
 from typing import Optional, Sequence
 
 import jax
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 Candidate = tuple[str, ...]
@@ -159,14 +160,26 @@ def _active_rules() -> dict | None:
     return mr[1] if mr else None
 
 
-def _mesh_context(mesh: Mesh):
-    """API-drift shim: jax.set_mesh(mesh) is the context-manager form on
-    jax >= 0.7; on older releases the Mesh object itself is the context
-    manager that activates it."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+def make_mesh(shape: Sequence[int], axes: Sequence[str], devices=None) -> Mesh:
+    """The one mesh constructor: every axis ``Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which sharding is
+    part of every value's type: a one-slot admission update would have to be
+    resharded by hand before ``dynamic_update_slice`` accepts it, and
+    ``with_sharding_constraint`` inside model code would fight the types.
+    Every program here places data with ``NamedSharding`` + constraints and
+    lets the partitioner propagate, which is ``Auto`` semantics — so every
+    mesh (slot mesh, LM meshes, pipeline mesh, elastic re-mesh) is built
+    here, and meshes of both kinds never mix. ``devices`` (default: all)
+    takes the first ``prod(shape)`` devices in order, as an elastic re-mesh
+    onto survivors needs.
+    """
+    shape, axes = tuple(shape), tuple(axes)
+    types = (jax.sharding.AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(shape, axes, axis_types=types)
+    arr = np.asarray(list(devices)[: math.prod(shape)]).reshape(shape)
+    return Mesh(arr, axes, axis_types=types)
 
 
 @contextlib.contextmanager
@@ -175,7 +188,7 @@ def use_mesh_rules(mesh: Mesh, rules: dict[str, list[Candidate]] | None = None):
     prev = _active()
     _ctx.mesh_rules = (mesh, rules or DEFAULT_RULES)
     try:
-        with _mesh_context(mesh):
+        with jax.set_mesh(mesh):
             yield
     finally:
         _ctx.mesh_rules = prev
@@ -194,21 +207,16 @@ def constraint(x: jax.Array, axes: Sequence[Optional[str]]) -> jax.Array:
         return x
     mesh, rules = mr
     spec = partition_spec(x.shape, axes, mesh, rules)
-    # get_abstract_mesh is jax >= 0.5-only; older releases have no abstract-
-    # mesh tracking, so the rules-table mesh is authoritative there
-    cur = getattr(jax.sharding, "get_abstract_mesh", lambda: None)()
+    cur = jax.sharding.get_abstract_mesh()
     manual: set[str] = set()
     use_mesh = mesh
-    if cur is not None and not getattr(cur, "empty", True) and tuple(
-        getattr(cur, "axis_names", ())
-    ) == tuple(mesh.axis_names):
+    if not cur.empty and tuple(cur.axis_names) == tuple(mesh.axis_names):
         use_mesh = cur
-        try:
-            for name, ty in zip(cur.axis_names, cur.axis_types):
-                if "Manual" in str(ty):
-                    manual.add(name)
-        except Exception:
-            pass
+        manual = {
+            name
+            for name, ty in zip(cur.axis_names, cur.axis_types)
+            if ty == jax.sharding.AxisType.Manual
+        }
     if manual:
 
         def strip(entry):

@@ -15,6 +15,8 @@ import dataclasses
 
 import jax
 
+from repro.parallel import make_mesh
+
 
 @dataclasses.dataclass(frozen=True)
 class MeshPlan:
@@ -94,7 +96,4 @@ def build_mesh(plan: MeshPlan, devices=None):
     n = plan.n_devices
     if n > len(devices):
         raise ValueError(f"plan needs {n} devices, have {len(devices)}")
-    import numpy as np
-
-    arr = np.asarray(devices[:n]).reshape(plan.shape)
-    return jax.sharding.Mesh(arr, plan.axes)
+    return make_mesh(plan.shape, plan.axes, devices=devices)

@@ -77,7 +77,8 @@ def test_collective_wire_model():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.analysis.hlo import analyze_module
-        mesh = jax.make_mesh((8,), ("m",))
+        from repro.parallel import make_mesh
+        mesh = make_mesh((8,), ("m",))
         def f(x, w):  # contract the sharded dim -> one all-reduce
             return x @ w
         x = jax.ShapeDtypeStruct((64, 512), jnp.float32)
@@ -104,7 +105,8 @@ def test_reduce_scatter_recognition():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.analysis.hlo import analyze_module
-        mesh = jax.make_mesh((8,), ("m",))
+        from repro.parallel import make_mesh
+        mesh = make_mesh((8,), ("m",))
         def f(x, w):
             y = x @ w  # partial over m
             return jax.lax.with_sharding_constraint(

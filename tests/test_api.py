@@ -242,6 +242,30 @@ def test_detect_vmem_budget_platform_table():
     assert tiling.detect_vmem_budget(StatsDev()) == int(4 * 1024 * 1024 * 0.5)
 
 
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+def test_vmem_budget_unknown_kind_raises_only_on_tpu(platform):
+    """A TPU whose device_kind the platform table lacks has no known VMEM
+    budget: resolving one raises instead of guessing. Off TPU the labelled
+    v4/v5 default keeps CPU runs deterministic."""
+    from repro.kernels.mr_step import tiling
+
+    class UnknownDev:
+        device_kind = "TPU v99 hypothetical"
+
+        def memory_stats(self):
+            return {}
+
+    UnknownDev.platform = platform
+    if platform == "tpu":
+        with pytest.raises(ValueError, match="PLATFORM_VMEM_BYTES"):
+            tiling.resolve_vmem_budget(UnknownDev())
+    else:
+        assert tiling.resolve_vmem_budget(UnknownDev()) == (
+            int(tiling.VMEM_BYTES_PER_CORE * tiling.VMEM_BUDGET_FRACTION),
+            "default",
+        )
+
+
 @pytest.mark.parametrize("encoder", ["ltc", "node"])
 def test_substep_vmem_model_and_auto_tile(encoder):
     """config_vmem_bytes dispatches to the substep-cell residency models and

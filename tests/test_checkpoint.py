@@ -15,6 +15,7 @@ from repro.checkpoint import (
     restore_checkpoint,
     save_checkpoint,
 )
+from repro.parallel import make_mesh
 
 
 def _state(key=0):
@@ -80,7 +81,7 @@ def test_mesh_axes_mismatch_rejected(tmp_path):
     plan sharding over DIFFERENT axes — up front, with a clear error, not a
     shape mismatch deep inside device_put. Matching (or absent) axes pass."""
     s = _state()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     save_checkpoint(tmp_path, 2, s, mesh=mesh)
     like = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), s)
     with pytest.raises(ValueError, match="mesh axes .* shards over"):
@@ -115,12 +116,13 @@ def test_reshard_on_restore_across_meshes(run_devices_fixture=None):
         import jax, jax.numpy as jnp, numpy as np, tempfile
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import save_checkpoint, restore_checkpoint
+        from repro.parallel import make_mesh
         d = tempfile.mkdtemp()
-        mesh8 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh8 = make_mesh((4, 2), ("data", "model"))
         x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
         xs = jax.device_put(x, NamedSharding(mesh8, P("data", "model")))
         save_checkpoint(d, 1, {"x": xs}, mesh=mesh8)
-        mesh4 = jax.make_mesh((2, 2), ("data", "model"))
+        mesh4 = make_mesh((2, 2), ("data", "model"))
         sh = {"x": NamedSharding(mesh4, P("model", "data"))}
         like = {"x": jax.ShapeDtypeStruct((8, 8), jnp.float32)}
         r, man = restore_checkpoint(d, 1, like, sh)

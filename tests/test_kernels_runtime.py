@@ -1,48 +1,21 @@
-"""kernels/runtime compat+dispatch layer: shims pinned against both API
-spellings, dispatch policy, and interpret-vs-reference parity for all three
-kernel families routed through pallas_call_compat."""
+"""kernels/runtime: the one pallas_call constructor (spec pairs, SMEM
+operands, compiler params), the dispatch policy, and interpret-vs-reference
+parity for the kernel families routed through it."""
 
 from __future__ import annotations
-
-import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import runtime as rt
 
 
-# --- CompilerParams spelling shim -------------------------------------------
-class _ParamsNew:
-    def __init__(self, **kw):
-        self.kw = kw
-
-
-class _ParamsOld:
-    def __init__(self, **kw):
-        self.kw = kw
-
-
+# --- the one pallas_call constructor ----------------------------------------
 def test_compiler_params_resolves_new_spelling():
-    ns = types.SimpleNamespace(CompilerParams=_ParamsNew)
-    assert rt.resolve_compiler_params_cls(ns) is _ParamsNew
-
-
-def test_compiler_params_resolves_old_spelling():
-    ns = types.SimpleNamespace(TPUCompilerParams=_ParamsOld)
-    assert rt.resolve_compiler_params_cls(ns) is _ParamsOld
-
-
-def test_compiler_params_prefers_new_when_both_exist():
-    ns = types.SimpleNamespace(CompilerParams=_ParamsNew, TPUCompilerParams=_ParamsOld)
-    assert rt.resolve_compiler_params_cls(ns) is _ParamsNew
-
-
-def test_compiler_params_unknown_namespace_raises():
-    with pytest.raises(AttributeError, match="runtime.py"):
-        rt.resolve_compiler_params_cls(types.SimpleNamespace())
+    assert isinstance(rt.compiler_params(), pltpu.CompilerParams)
 
 
 def test_compiler_params_builds_on_installed_jax():
@@ -50,25 +23,37 @@ def test_compiler_params_builds_on_installed_jax():
     assert tuple(p.dimension_semantics) == (rt.PARALLEL, rt.ARBITRARY)
 
 
-# --- BlockSpec argument-order shim ------------------------------------------
-class _SpecBlockShapeFirst:
-    def __init__(self, block_shape=None, index_map=None):
-        self.block_shape, self.index_map = block_shape, index_map
-
-
-class _SpecIndexMapFirst:
-    def __init__(self, index_map=None, block_shape=None):
-        self.block_shape, self.index_map = block_shape, index_map
-
-
-def test_blockspec_order_detection_both_orders():
-    assert rt.blockspec_block_shape_first(_SpecBlockShapeFirst)
-    assert not rt.blockspec_block_shape_first(_SpecIndexMapFirst)
-
-
 def test_block_spec_builds_on_installed_jax():
     spec = rt.block_spec((8, 128), lambda i: (i, 0))
     assert tuple(spec.block_shape) == (8, 128)
+
+
+def test_smem_spec_places_operand_in_smem():
+    assert rt.smem_spec().memory_space == pltpu.SMEM
+
+
+def test_pallas_call_squeezed_time_major_stream_and_smem_scalars():
+    """The streaming layout every scan kernel uses: a time-major operand
+    read one ``(None, bb, D)`` block (time dim squeezed) per grid step, plus
+    per-step scalars read from an SMEM operand — under the interpreter it
+    must equal the plain jnp expression."""
+    T, B, D, bb = 5, 16, 128, 8
+
+    def kernel(x_ref, dt_ref, o_ref):
+        o_ref[...] = x_ref[...] * dt_ref[pl.program_id(1), 0]
+
+    x = jax.random.normal(jax.random.key(0), (T, B, D), jnp.float32)
+    dts = jnp.arange(1.0, T + 1.0, dtype=jnp.float32).reshape(T, 1)
+    out = rt.pallas_call(
+        kernel,
+        grid=(B // bb, T),
+        in_specs=[((None, bb, D), lambda ib, t: (t, ib, 0)), rt.smem_spec()],
+        out_specs=((None, bb, D), lambda ib, t: (t, ib, 0)),
+        out_shape=jax.ShapeDtypeStruct((T, B, D), jnp.float32),
+        dimension_semantics=(rt.PARALLEL, rt.ARBITRARY),
+        interpret=True,
+    )(x, dts)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(x * dts[:, :, None]))
 
 
 # --- dispatch policy ---------------------------------------------------------
@@ -89,7 +74,7 @@ def test_dispatch_cpu_interpret_vs_reference():
     assert rt.resolve_dispatch(False, False, backend="cpu") is rt.Dispatch.REFERENCE
 
 
-# --- interpret-vs-reference parity through the compat layer ------------------
+# --- interpret-vs-reference parity through the runtime ------------------------
 def test_gru_interpret_matches_reference():
     from repro.core.neural_flow import gru_scan_ref, init_gru
     from repro.kernels.gru_scan.ops import gru_scan

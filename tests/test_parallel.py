@@ -35,7 +35,7 @@ def test_sharded_train_step_matches_single_device():
         # single-device reference
         loss_ref, _ = jax.jit(lambda p, b: M.train_loss(p, b, cfg))(params, batch)
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = rules_mod.make_mesh((2, 2), ("data", "model"))
         rules = rules_mod.DEFAULT_RULES
         with rules_mod.use_mesh_rules(mesh, rules):
             jitted, state_sh, batch_sh, _ = make_train_step(cfg, shape, mesh, rules, donate=False)
@@ -90,8 +90,8 @@ def test_grad_compression_int8_error_feedback():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.optim.compression import compress_reduce_grads, init_error_buffers
-        from repro.parallel.pipeline import shard_map  # check_rep/check_vma compat
-        mesh = jax.make_mesh((4,), ("pod",))
+        from repro.parallel import make_mesh
+        mesh = make_mesh((4,), ("pod",))
         g_global = jax.random.normal(jax.random.key(0), (4, 64, 8))  # per-pod grads
         mean_ref = jnp.mean(g_global, axis=0)
 
@@ -99,8 +99,8 @@ def test_grad_compression_int8_error_feedback():
             out, e2 = compress_reduce_grads({"w": g[0]}, {"w": e[0]}, "pod")
             return out["w"], e2["w"]
 
-        fn = shard_map(body, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                       out_specs=(P(), P("pod")), check_replication=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                           out_specs=(P(), P("pod")), check_vma=False)
         # one step: quantization error bounded
         e0 = jnp.zeros_like(g_global)
         red1, e1 = fn(g_global, e0)
@@ -171,7 +171,7 @@ def test_multipod_train_step_compiles():
         from repro.parallel.steps import make_train_step
         cfg = get_config("qwen2.5-3b", smoke=True)
         shape = ShapeConfig("t", 32, 8, "train")
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = rules_mod.make_mesh((2, 2, 2), ("pod", "data", "model"))
         rules = rules_mod.DEFAULT_RULES
         with rules_mod.use_mesh_rules(mesh, rules):
             jitted, state_sh, batch_sh, abstract_args = make_train_step(

@@ -49,9 +49,10 @@ def test_dryrun_machinery_small_mesh():
         """
         import json, pathlib, tempfile, jax
         import repro.launch.mesh as mesh_mod
+        from repro.parallel import make_mesh
         mesh_mod.make_production_mesh = lambda multi_pod=False: (
-            jax.make_mesh((2, 2, 2), ("pod", "data", "model")) if multi_pod
-            else jax.make_mesh((2, 2), ("data", "model")))
+            make_mesh((2, 2, 2), ("pod", "data", "model")) if multi_pod
+            else make_mesh((2, 2), ("data", "model")))
         import repro.configs.base as B
         # smoke dims + tiny shape so the cell compiles in seconds
         B.SHAPES["train_4k"] = B.ShapeConfig("train_4k", 64, 8, "train")
@@ -114,3 +115,43 @@ def test_mr_end_to_end_quickstart():
     assert hist[-1]["recon_mse"] < 0.1, hist
     theta = recover_coefficients(params, cfg, jnp.asarray(yw), None, n_active=4)
     assert int((np.abs(np.asarray(theta)) > 0).sum()) <= 4
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    """Entry points keep JAX's compile cache in $JAX_COMPILATION_CACHE_DIR
+    when set, else at the fixed <checkout>/.jax_cache — never elsewhere."""
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        path = enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    assert path == (str(tmp_path) if from_env else str(pathlib.Path(REPO) / ".jax_cache"))
+
+
+def test_serve_mr_serves_at_float32_matmul_precision(monkeypatch):
+    """serve_mr runs the recovery math at float32 matmul precision, which the
+    CPU computes anyway and a TPU only when asked: at its default (one
+    bfloat16 pass) a stream of the acceptance scenario converged early,
+    outside the baseline tolerance."""
+    import jax
+
+    from repro.launch import compile_cache, serve_mr
+
+    seen = []
+    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: "")
+    monkeypatch.setattr(
+        serve_mr, "_serve", lambda args: seen.append(jax.config.jax_default_matmul_precision)
+    )
+    serve_mr.main(["--streams", "1"])
+    assert seen == ["float32"]
+    assert jax.config.jax_default_matmul_precision is None  # nothing leaks past main

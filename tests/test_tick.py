@@ -277,6 +277,20 @@ def test_tick_vmem_bytes_monotonic_in_bank_size():
     assert q < sizes[1]  # int8 weights shrink the resident bank
 
 
+@pytest.mark.parametrize("int8", [False, True])
+def test_tick_vmem_footprint_bounds_residency(int8):
+    """The VMEM a grid step allocates (tile-padded, double-buffered blocks)
+    is never below the bank's logical residency, and grows with the bank."""
+    cfg = _mr_cfg()
+    for s in (1, 2, 4):
+        foot = tiling.tick_vmem_footprint(cfg, TCFG, slots_per_bank=s, int8=int8)
+        assert foot >= tiling.tick_vmem_bytes(cfg, TCFG, slots_per_bank=s, int8=int8)
+    one, two = (
+        tiling.tick_vmem_footprint(cfg, TCFG, slots_per_bank=s, int8=int8) for s in (1, 2)
+    )
+    assert one < two < 2 * one + 1  # per-slot blocks scale; the scratch is shared
+
+
 def test_auto_slots_per_bank_policy():
     cfg = _mr_cfg()
     assert tiling.auto_slots_per_bank(cfg, TCFG, 8, None) == 8  # no budget: whole shard
