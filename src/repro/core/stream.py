@@ -163,9 +163,10 @@ def shard_slots(state: SlotState, mesh) -> SlotState:
 
 
 def cold_start(key: jax.Array, cfg: MRConfig) -> tuple[MRParams, Any]:
-    """Fresh (params, opt_state) for one admission."""
-    params = init_mr(key, cfg)
-    return params, adamw_init(params)
+    """Fresh (params, opt_state) for one admission (eager; span ``mr.cold_start``)."""
+    with jax.profiler.TraceAnnotation("mr.cold_start"):
+        params = init_mr(key, cfg)
+        return params, adamw_init(params)
 
 
 def init_slots(key: jax.Array, cfg: MRConfig, scfg: StreamConfig, n_slots: int) -> SlotState:
@@ -637,16 +638,10 @@ class RecoveryService:
         self._prio_of: dict[int, int] = {}  # stream_id -> submitted tier
         self._undrained: list[StreamResult] = []
         # -- resilience / latency accounting (runtime/resilience.py) ---------
-        # per-tick wall latency (ms) + per-shard heartbeats feeding the
-        # straggler detector; serve_mr surfaces p50/p99 and the flags.
-        # checkpointer is attached by RecoveryPlan.make_service when the
-        # TickSpec requests periodic service snapshots.
-        from repro.runtime.heartbeat import HeartbeatRegistry, StragglerDetector
-
+        # per-tick wall latency (ms); serve_mr surfaces p50/p99. checkpointer
+        # is attached by RecoveryPlan.make_service when the TickSpec requests
+        # periodic service snapshots.
         self.tick_ms: list[float] = []
-        self.registry = HeartbeatRegistry()
-        self.stragglers = StragglerDetector(self.registry)
-        self.straggler_flags: list[str] = []
         self.checkpointer = None
         # -- device-resident control plane (control.py) ----------------------
         self.control_plane = control
@@ -764,17 +759,18 @@ class RecoveryService:
         shard = min(range(cp.shards), key=lambda i: (len(self._inflight[i]), i))
         if len(self._inflight[shard]) >= cp.queue_capacity:
             return None
-        params, _ = cold_start(jax.random.fold_in(self.key, 1000 + sid), self.cfg)
-        with self._mesh_ctx():
-            self.control = cp.enqueue(
-                self.control,
-                jnp.int32(shard),
-                jnp.int32(sid),
-                jnp.asarray(history_y, jnp.float32),
-                jnp.asarray(history_u, jnp.float32),
-                params,
-                jnp.int32(priority),
-            )
+        with jax.profiler.TraceAnnotation("mr.enqueue", stream_id=sid):
+            params, _ = cold_start(jax.random.fold_in(self.key, 1000 + sid), self.cfg)
+            with self._mesh_ctx():
+                self.control = cp.enqueue(
+                    self.control,
+                    jnp.int32(shard),
+                    jnp.int32(sid),
+                    jnp.asarray(history_y, jnp.float32),
+                    jnp.asarray(history_u, jnp.float32),
+                    params,
+                    jnp.int32(priority),
+                )
         self._inflight[shard].add(sid)
         self._pending.add(sid)
         self._seen_done.discard(sid)
@@ -802,6 +798,8 @@ class RecoveryService:
         return entry
 
     def _admit_into(self, slot: int):
+        """Admit the next queued stream into ``slot`` (span ``mr.admit``), or
+        mark the slot empty when nothing waits."""
         if not self.queue:
             with self._mesh_ctx():
                 self.state = deactivate(self.state, jnp.int32(slot))
@@ -813,27 +811,30 @@ class RecoveryService:
             self._slot_view[slot] = -1
             self._prio_view[slot] = 0
             return None
-        stream_id, buf_y, buf_u, prio = self._queue_pop()
-        warm_params = self._warm_get(stream_id)
-        if warm_params is not None:
-            params = warm_params
-            opt = adamw_init(params)
-        else:
-            params, opt = cold_start(jax.random.fold_in(self.key, 1000 + stream_id), self.cfg)
-        with self._mesh_ctx():
-            self.state = admit(
-                self.state,
-                jnp.int32(slot),
-                jnp.int32(stream_id),
-                jnp.asarray(buf_y),
-                jnp.asarray(buf_u),
-                params,
-                opt,
-            )
-        if self.mesh is not None:
-            # admission mixes replicated single-slot operands into the update;
-            # re-pin the slot shard so every later tick sees the same layout
-            self._reshard()
+        with jax.profiler.TraceAnnotation("mr.admit", slot=slot) as span:
+            stream_id, buf_y, buf_u, prio = self._queue_pop()
+            span.set_metadata(stream_id=stream_id)
+            warm_params = self._warm_get(stream_id)
+            if warm_params is not None:
+                params = warm_params
+                opt = adamw_init(params)
+            else:
+                params, opt = cold_start(jax.random.fold_in(self.key, 1000 + stream_id), self.cfg)
+            with self._mesh_ctx():
+                self.state = admit(
+                    self.state,
+                    jnp.int32(slot),
+                    jnp.int32(stream_id),
+                    jnp.asarray(buf_y),
+                    jnp.asarray(buf_u),
+                    params,
+                    opt,
+                )
+            if self.mesh is not None:
+                # admission mixes replicated single-slot operands into the
+                # update; re-pin the slot shard so every later tick sees the
+                # same layout
+                self._reshard()
         self._active_view[slot] = True
         self._slot_view[slot] = int(stream_id)
         self._delta_view[slot] = np.inf
@@ -850,48 +851,54 @@ class RecoveryService:
         original tier, then the arrival is admitted into the freed slot.
         Warm slots (past min_steps) are never preempted — they are about to
         converge and evict on their own. Terminates: each displacement
-        strictly raises the resident tier multiset."""
-        while self.queue:
-            prio = max(e[3] for e in self.queue)
-            cold = [
-                s
-                for s in range(self.n_slots)
-                if self._active_view[s] and self._steps_view[s] < self.scfg.min_steps
-            ]
-            if not cold:
-                return
-            victim = min(cold, key=lambda s: (self._prio_view[s], s))
-            if prio <= self._prio_view[victim]:
-                return
-            vid = int(self._slot_view[victim])
-            st = self.state
-            self._warm_put(vid, jax.tree.map(lambda a: a[victim], st.params))
-            self.queue.append(
-                (
-                    vid,
-                    self._host_read(st.buf_y[victim]),
-                    self._host_read(st.buf_u[victim]),
-                    int(self._prio_view[victim]),
+        strictly raises the resident tier multiset. The pass runs under the
+        span ``mr.preempt`` whenever an arrival waits."""
+        if not self.queue:
+            return
+        with jax.profiler.TraceAnnotation("mr.preempt"):
+            while self.queue:
+                prio = max(e[3] for e in self.queue)
+                cold = [
+                    s
+                    for s in range(self.n_slots)
+                    if self._active_view[s] and self._steps_view[s] < self.scfg.min_steps
+                ]
+                if not cold:
+                    return
+                victim = min(cold, key=lambda s: (self._prio_view[s], s))
+                if prio <= self._prio_view[victim]:
+                    return
+                vid = int(self._slot_view[victim])
+                st = self.state
+                self._warm_put(vid, jax.tree.map(lambda a: a[victim], st.params))
+                self.queue.append(
+                    (
+                        vid,
+                        self._host_read(st.buf_y[victim]),
+                        self._host_read(st.buf_u[victim]),
+                        int(self._prio_view[victim]),
+                    )
                 )
-            )
-            # _admit_into pops by tier, so it picks the arrival we just
-            # compared (the re-queued victim sits strictly below it)
-            self._admit_into(victim)
+                # _admit_into pops by tier, so it picks the arrival we just
+                # compared (the re-queued victim sits strictly below it)
+                self._admit_into(victim)
 
     def fill_slots(self) -> list[int]:
         """Bootstrap: admit queued streams into every empty slot.
 
         Device control plane: one ``pump`` program drains the on-device rings
-        into every idle slot, then a snapshot refreshes the host views.
+        into every idle slot, then a snapshot refreshes the host views (both
+        under the span ``mr.pump``).
         """
         if self.control_plane is not None:
             self._drain_overflow()
             before = {int(i) for i in self._slot_view if i >= 0}
-            with self._mesh_ctx():
-                self.state, self.control, status = self.control_plane.pump(
-                    self.state, self.control
-                )
-            self._snapshot(status)
+            with jax.profiler.TraceAnnotation("mr.pump"):
+                with self._mesh_ctx():
+                    self.state, self.control, status = self.control_plane.pump(
+                        self.state, self.control
+                    )
+                self._snapshot(status)
             return [int(i) for i in self._slot_view if i >= 0 and int(i) not in before]
         admitted = []
         active = self._host_read(self.state.active)
@@ -916,26 +923,31 @@ class RecoveryService:
         return [int(i) for i in self._host_read(self.state.stream_id)]
 
     def _evict(self, slot: int, reason: str) -> StreamResult:
+        """Read ``slot``'s result back and keep its params warm (span
+        ``mr.evict``, metadata from the host's slot view)."""
         st = self.state
-        sid = int(self._host_read(st.stream_id[slot]))
-        theta = st.theta[slot]
-        if self.quant:
-            yw, uw = _slot_windows(
-                st.buf_y[slot], st.buf_u[slot], st.mean[slot], st.scale[slot], self.scfg
+        with jax.profiler.TraceAnnotation(
+            "mr.evict", stream_id=int(self._slot_view[slot]), slot=slot
+        ):
+            sid = int(self._host_read(st.stream_id[slot]))
+            theta = st.theta[slot]
+            if self.quant:
+                yw, uw = _slot_windows(
+                    st.buf_y[slot], st.buf_u[slot], st.mean[slot], st.scale[slot], self.scfg
+                )
+                slot_params = jax.tree.map(lambda a: a[slot], st.params)
+                theta = readout_theta(slot_params, self.cfg, yw, uw, quant=True)
+            res = StreamResult(
+                stream_id=sid,
+                theta=self._host_read(theta),
+                mean=self._host_read(st.mean[slot]),
+                scale=self._host_read(st.scale[slot]),
+                steps=int(self._host_read(st.steps[slot])),
+                reason=reason,
             )
-            slot_params = jax.tree.map(lambda a: a[slot], st.params)
-            theta = readout_theta(slot_params, self.cfg, yw, uw, quant=True)
-        res = StreamResult(
-            stream_id=sid,
-            theta=self._host_read(theta),
-            mean=self._host_read(st.mean[slot]),
-            scale=self._host_read(st.scale[slot]),
-            steps=int(self._host_read(st.steps[slot])),
-            reason=reason,
-        )
-        self.results[sid] = res
-        self._undrained.append(res)
-        self._warm_put(sid, jax.tree.map(lambda a: a[slot], st.params))
+            self.results[sid] = res
+            self._undrained.append(res)
+            self._warm_put(sid, jax.tree.map(lambda a: a[slot], st.params))
         return res
 
     def _snapshot(self, status) -> list[StreamResult]:
@@ -943,56 +955,58 @@ class RecoveryService:
         [S, 5] status and drain the on-device event log into StreamResults.
 
         The ONLY device->host readbacks on the device path happen here — two
-        per snapshot (status + event log), every ``snapshot_period`` ticks.
+        per snapshot (status + event log), every ``snapshot_period`` ticks —
+        all under the span ``mr.snapshot``.
         """
         from repro.core import control as control_mod
 
-        cp = self.control_plane
-        prev_slots = self._slot_view.copy()
-        snap = self._host_read(status)
-        self._delta_view = snap[:, 0].copy()
-        self._loss_view = snap[:, 1].copy()
-        self._steps_view = snap[:, 2].astype(np.int64)
-        self._active_view = snap[:, 3] > 0
-        self._slot_view = snap[:, 4].astype(np.int64)
-        for s in range(self.n_slots):
-            sid = int(self._slot_view[s])
-            self._prio_view[s] = self._prio_of.get(sid, 0) if sid >= 0 else 0
-        with self._mesh_ctx():
-            self.control, events = cp.drain(self.control)
-        new_results = []
-        for sid, steps, code, theta, mean, scale in control_mod.decode_events(
-            self._host_read(events), self.cfg
-        ):
-            res = StreamResult(
-                stream_id=sid,
-                theta=theta,
-                mean=mean,
-                scale=scale,
-                steps=steps,
-                reason="converged" if code == 1 else "budget",
-            )
-            self.results[sid] = res
-            self._undrained.append(res)
-            self._pending.discard(sid)
-            self._seen_done.add(sid)
-            new_results.append(res)
-        # an enqueued id leaves its shard's in-flight set once the snapshot
-        # shows it admitted (slot view) or already completed (event log); an
-        # id that WAS resident and is now neither resident nor completed was
-        # preempted back into its shard's queue — re-count it in-flight so
-        # the host-side occupancy bound stays conservative
-        resident = {int(i) for i in self._slot_view if i >= 0}
-        slots_per_shard = self.n_slots // cp.shards
-        for s in range(self.n_slots):
-            sid = int(prev_slots[s])
-            if sid >= 0 and sid not in resident and sid not in self._seen_done:
-                self._inflight[s // slots_per_shard].add(sid)
-        settled = resident | self._seen_done
-        for shard_ids in self._inflight:
-            shard_ids.difference_update(settled)
-        self._ticks_since_snapshot = 0
-        self._drain_overflow()
+        with jax.profiler.TraceAnnotation("mr.snapshot"):
+            cp = self.control_plane
+            prev_slots = self._slot_view.copy()
+            snap = self._host_read(status)
+            self._delta_view = snap[:, 0].copy()
+            self._loss_view = snap[:, 1].copy()
+            self._steps_view = snap[:, 2].astype(np.int64)
+            self._active_view = snap[:, 3] > 0
+            self._slot_view = snap[:, 4].astype(np.int64)
+            for s in range(self.n_slots):
+                sid = int(self._slot_view[s])
+                self._prio_view[s] = self._prio_of.get(sid, 0) if sid >= 0 else 0
+            with self._mesh_ctx():
+                self.control, events = cp.drain(self.control)
+            new_results = []
+            for sid, steps, code, theta, mean, scale in control_mod.decode_events(
+                self._host_read(events), self.cfg
+            ):
+                res = StreamResult(
+                    stream_id=sid,
+                    theta=theta,
+                    mean=mean,
+                    scale=scale,
+                    steps=steps,
+                    reason="converged" if code == 1 else "budget",
+                )
+                self.results[sid] = res
+                self._undrained.append(res)
+                self._pending.discard(sid)
+                self._seen_done.add(sid)
+                new_results.append(res)
+            # an enqueued id leaves its shard's in-flight set once the snapshot
+            # shows it admitted (slot view) or already completed (event log); an
+            # id that WAS resident and is now neither resident nor completed was
+            # preempted back into its shard's queue — re-count it in-flight so
+            # the host-side occupancy bound stays conservative
+            resident = {int(i) for i in self._slot_view if i >= 0}
+            slots_per_shard = self.n_slots // cp.shards
+            for s in range(self.n_slots):
+                sid = int(prev_slots[s])
+                if sid >= 0 and sid not in resident and sid not in self._seen_done:
+                    self._inflight[s // slots_per_shard].add(sid)
+            settled = resident | self._seen_done
+            for shard_ids in self._inflight:
+                shard_ids.difference_update(settled)
+            self._ticks_since_snapshot = 0
+            self._drain_overflow()
         return new_results
 
     def tick_once(self, chunks_y: np.ndarray, chunks_u: np.ndarray | None = None) -> dict:
@@ -1003,51 +1017,61 @@ class RecoveryService:
         at snapshot ticks (every ``snapshot_period``), so ``sync_log`` records
         0 for steady-state ticks. Between snapshots the info dict serves the
         cached (snapshot-stale) views.
+
+        Profiler spans (``jax.profiler.TraceAnnotation``; they cost about a
+        microsecond each when no profiler runs): ``mr.tick`` (metadata
+        ``tick``, the number the info dict returns) encloses the whole tick,
+        ``mr.tick.dispatch`` the launch of the compiled tick, and then each
+        plane's own spans (``_host_plane_tick``, ``_device_plane_tick``).
         """
         t0 = time.perf_counter()
         syncs0 = self.counters["host_syncs"]
-        S, C, m = self.n_slots, self.scfg.chunk, self.cfg.input_dim
         if chunks_u is None:
-            chunks_u = np.zeros((S, C, m), np.float32)
-        if self.control_plane is not None:
-            cp = self.control_plane
-            with self._mesh_ctx():
-                self.state, self.control, status = cp.tick(
-                    self.state,
-                    self.control,
+            chunks_u = np.zeros((self.n_slots, self.scfg.chunk, self.cfg.input_dim), np.float32)
+        cp = self.control_plane
+        with jax.profiler.TraceAnnotation("mr.tick", tick=self.ticks + 1):
+            with jax.profiler.TraceAnnotation("mr.tick.dispatch"), self._mesh_ctx():
+                args = (
                     jnp.asarray(chunks_y, jnp.float32),
                     jnp.asarray(chunks_u, jnp.float32),
                     jax.random.fold_in(self.key, self.ticks),
                 )
+                if cp is None:
+                    out = self._tick(self.state, *args)
+                else:
+                    self.state, self.control, out = cp.tick(self.state, self.control, *args)
             self.ticks += 1
-            self._ticks_since_snapshot += 1
-            evicted: list[StreamResult] = []
-            if self._ticks_since_snapshot >= cp.snapshot_period:
-                evicted = self._snapshot(status)
-            info = {
-                "tick": self.ticks,
-                "evicted": evicted,
-                "active": int(self._active_view.sum()),
-                "delta": self._delta_view,
-                "loss": self._loss_view,
-                "steps": self._steps_view,
-            }
+            info = self._host_plane_tick(out) if cp is None else self._device_plane_tick(out)
             # checkpoint before closing the sync window so a snapshot tick's
             # staging readbacks land in THIS tick's sync_log delta (honest
             # per-tick attribution; period=0 keeps steady state untouched)
             if self.checkpointer is not None:
                 self.checkpointer.after_tick(self)
-            self._finish_tick(t0)
-            self.sync_log.append(self.counters["host_syncs"] - syncs0)
-            return info
-        with self._mesh_ctx():
-            out = self._tick(
-                self.state,
-                jnp.asarray(chunks_y, jnp.float32),
-                jnp.asarray(chunks_u, jnp.float32),
-                jax.random.fold_in(self.key, self.ticks),
-            )
-        self.ticks += 1
+        self.tick_ms.append((time.perf_counter() - t0) * 1e3)
+        self.sync_log.append(self.counters["host_syncs"] - syncs0)
+        return info
+
+    def _device_plane_tick(self, status) -> dict:
+        """After ``control.tick_device``: a snapshot every ``snapshot_period``
+        ticks (span ``mr.snapshot``), else the cached views."""
+        self._ticks_since_snapshot += 1
+        evicted: list[StreamResult] = []
+        if self._ticks_since_snapshot >= self.control_plane.snapshot_period:
+            evicted = self._snapshot(status)
+        return {
+            "tick": self.ticks,
+            "evicted": evicted,
+            "active": int(self._active_view.sum()),
+            "delta": self._delta_view,
+            "loss": self._loss_view,
+            "steps": self._steps_view,
+        }
+
+    def _host_plane_tick(self, out) -> dict:
+        """After the compiled tick on the host plane: read the per-slot status
+        back (span ``mr.tick.readback``), then evict, refill and preempt
+        (span ``mr.tick.scan``, parent of ``mr.evict`` / ``mr.admit`` /
+        ``mr.preempt``)."""
         # kernel-path-aware sync accounting: the banked tick returns (state,
         # status) with every per-slot scalar packed into ONE array, so the
         # whole eviction scan costs a single host readback; the composite
@@ -1055,40 +1079,43 @@ class RecoveryService:
         # baseline of the ROADMAP device-resident-control-plane item).
         banked = not isinstance(out, SlotState)
         loss = None
-        if banked:
-            self.state, status = out
-            snap = self._host_read(status)
-            delta, loss = snap[:, 0], snap[:, 1]
-            steps, active = snap[:, 2].astype(np.int64), snap[:, 3] > 0
-        else:
-            self.state = out
-            delta = self._host_read(self.state.delta)
-            steps = self._host_read(self.state.steps)
-            active = self._host_read(self.state.active)
+        with jax.profiler.TraceAnnotation("mr.tick.readback"):
+            if banked:
+                self.state, status = out
+                snap = self._host_read(status)
+                delta, loss = snap[:, 0], snap[:, 1]
+                steps, active = snap[:, 2].astype(np.int64), snap[:, 3] > 0
+            else:
+                self.state = out
+                delta = self._host_read(self.state.delta)
+                steps = self._host_read(self.state.steps)
+                active = self._host_read(self.state.active)
         self._active_view = np.asarray(active, bool).copy()
         self._delta_view = np.asarray(delta).copy()
         if banked:
             self._loss_view = np.asarray(loss).copy()
         self._steps_view = np.asarray(steps, np.int64)
         evicted = []
-        for s in range(S):
-            if not active[s]:
-                continue
-            converged = steps[s] >= self.scfg.min_steps and delta[s] <= self.scfg.delta_tol
-            budget = steps[s] >= self.scfg.max_steps
-            if converged or budget:
-                res = self._evict(s, "converged" if converged else "budget")
-                evicted.append(res)
-                self._admit_into(s)
-        # under pressure a higher-tier waiting arrival may displace a cold
-        # lower-tier slot (the host mirror of the device preemption pass)
-        self._preempt_host()
+        with jax.profiler.TraceAnnotation("mr.tick.scan"):
+            for s in range(self.n_slots):
+                if not active[s]:
+                    continue
+                converged = steps[s] >= self.scfg.min_steps and delta[s] <= self.scfg.delta_tol
+                budget = steps[s] >= self.scfg.max_steps
+                if converged or budget:
+                    res = self._evict(s, "converged" if converged else "budget")
+                    evicted.append(res)
+                    self._admit_into(s)
+            # under pressure a higher-tier waiting arrival may displace a cold
+            # lower-tier slot (the host mirror of the device preemption pass)
+            self._preempt_host()
         # eviction/admission updated the cached view in place, so the active
         # count never needs a second device readback (the polling-side fix:
         # `done` and `drain()` read the same host-side view)
         if not banked:
-            self._loss_view = np.array(self._host_read(self.state.loss))
-        info = {
+            with jax.profiler.TraceAnnotation("mr.tick.readback"):
+                self._loss_view = np.array(self._host_read(self.state.loss))
+        return {
             "tick": self.ticks,
             "evicted": evicted,
             "active": int(self._active_view.sum()),
@@ -1096,21 +1123,6 @@ class RecoveryService:
             "loss": self._loss_view,
             "steps": steps,
         }
-        if self.checkpointer is not None:
-            self.checkpointer.after_tick(self)
-        self._finish_tick(t0)
-        self.sync_log.append(self.counters["host_syncs"] - syncs0)
-        return info
-
-    def _finish_tick(self, t0: float):
-        """Latency accounting: per-tick wall ms, one heartbeat per shard
-        (host path beats a single logical worker), straggler re-check."""
-        dt = time.perf_counter() - t0
-        self.tick_ms.append(dt * 1e3)
-        n_workers = len(self._inflight) or 1
-        for i in range(n_workers):
-            self.registry.beat(f"shard{i}", self.ticks, dt)
-        self.straggler_flags = self.stragglers.check()
 
     def drain(self) -> list[StreamResult]:
         """Completed-stream results accumulated since the last drain.

@@ -462,7 +462,6 @@ def _serve(args) -> int:
         results = summary["results"]
         stats = {"ticks": summary["ticks"], "wall_s": time.time() - t0}
         tick_ms = [t for h in supervisor.history for t in h["tick_ms"]]
-        straggler_flags = summary["straggler_flags"]
         print(
             f"[serve_mr] chaos: {summary['restarts']} restart(s), final mesh "
             f"{summary['final_mesh']}, recovered_streams_fraction="
@@ -472,7 +471,6 @@ def _serve(args) -> int:
         stats = run_service(service, ys, us, args.max_ticks)
         results = service.results
         tick_ms = service.tick_ms
-        straggler_flags = service.straggler_flags
     n_done = len(results)
     print(
         f"[serve_mr] {n_done}/{args.streams} streams recovered in {stats['ticks']} ticks "
@@ -481,8 +479,7 @@ def _serve(args) -> int:
     if tick_ms:
         print(
             f"[serve_mr] tick latency: p50={float(np.percentile(tick_ms, 50)):.1f}ms "
-            f"p99={float(np.percentile(tick_ms, 99)):.1f}ms; "
-            f"stragglers={','.join(straggler_flags) or 'none'}"
+            f"p99={float(np.percentile(tick_ms, 99)):.1f}ms"
         )
     if service.sync_log:
         print(

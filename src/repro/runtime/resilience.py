@@ -99,10 +99,12 @@ class ServiceCheckpointer:
         self.save(service)
 
     def save(self, service):
-        """Stage device->host now (one counted sync), write async."""
-        tree = self._stage(service)
-        service.counters["host_syncs"] += 1
-        self.manager.maybe_save(service.ticks, tree, mesh=service.mesh, force=True)
+        """Stage device->host now (one counted sync), write async (span
+        ``mr.checkpoint``)."""
+        with jax.profiler.TraceAnnotation("mr.checkpoint"):
+            tree = self._stage(service)
+            service.counters["host_syncs"] += 1
+            self.manager.maybe_save(service.ticks, tree, mesh=service.mesh, force=True)
 
     def wait(self):
         self.manager.wait()
@@ -355,7 +357,6 @@ class ServiceSupervisor:
             "recovered_streams_fraction": len(results) / max(n_streams, 1),
             "p50_tick_ms": float(np.percentile(all_ms, 50)) if all_ms else 0.0,
             "p99_tick_ms": float(np.percentile(all_ms, 99)) if all_ms else 0.0,
-            "straggler_flags": list(self.service.straggler_flags),
             "final_mesh": tuple(self.plan.lowering.mesh_shape),
             "counters": {
                 k: sum(h["counters"][k] for h in self.history)
