@@ -162,11 +162,24 @@ def shard_slots(state: SlotState, mesh) -> SlotState:
     return jax.tree.map(put, state)
 
 
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def cold_start_program(key: jax.Array, cfg: MRConfig) -> tuple[MRParams, Any]:
+    """``init_mr`` + ``adamw_init`` as one compiled program (one per config)."""
+    params = init_mr(key, cfg)
+    return params, adamw_init(params)
+
+
+# the warm path's optimizer reset: one compiled program per params shape
+opt_init = jax.jit(adamw_init)
+
+
 def cold_start(key: jax.Array, cfg: MRConfig) -> tuple[MRParams, Any]:
-    """Fresh (params, opt_state) for one admission (eager; span ``mr.cold_start``)."""
+    """Fresh (params, opt_state) for one admission (span ``mr.cold_start``).
+
+    One dispatch of ``cold_start_program``: the per-stream admission path
+    traces nothing after the first stream of a config."""
     with jax.profiler.TraceAnnotation("mr.cold_start"):
-        params = init_mr(key, cfg)
-        return params, adamw_init(params)
+        return cold_start_program(key, cfg)
 
 
 def init_slots(key: jax.Array, cfg: MRConfig, scfg: StreamConfig, n_slots: int) -> SlotState:
@@ -235,6 +248,25 @@ def admit(
         active=_write_slot(state.active, slot, jnp.ones((), bool)),
         stream_id=_write_slot(state.stream_id, slot, stream_id),
     )
+
+
+@jax.jit
+def slot_result(state: SlotState, slot: jnp.ndarray) -> tuple[dict, MRParams]:
+    """One slot's answer gathered in one program (the state is not donated).
+
+    Returns ``(fields, params)``: ``fields`` holds the slot's ``stream_id``,
+    ``theta``, ``mean``, ``scale`` and ``steps`` for a single host read;
+    ``params`` is the slot's parameter tree, left on the device. ``slot`` is
+    traced, so one program serves every slot, as ``admit``'s does.
+    """
+
+    def one(leaf):
+        return jax.lax.dynamic_index_in_dim(leaf, slot, axis=0, keepdims=False)
+
+    fields = {
+        k: one(getattr(state, k)) for k in ("stream_id", "theta", "mean", "scale", "steps")
+    }
+    return fields, jax.tree.map(one, state.params)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -674,11 +706,12 @@ class RecoveryService:
             return contextlib.nullcontext()
         return use_mesh_rules(self.mesh, SLOT_RULES)
 
-    def _host_read(self, leaf) -> np.ndarray:
-        """Counted device->host readback (each is one host-sync point; on a
-        sharded service it gathers the slot axis across the whole mesh)."""
+    def _host_read(self, tree):
+        """Counted device->host readback of an array or a pytree of arrays
+        (one ``jax.device_get``, so one host-sync point; on a sharded service
+        it gathers the slot axis across the whole mesh)."""
         self.counters["host_syncs"] += 1
-        return np.asarray(leaf)
+        return jax.device_get(tree)
 
     def _reshard(self):
         """Re-pin the slot shard after a host-driven state update."""
@@ -817,7 +850,7 @@ class RecoveryService:
             warm_params = self._warm_get(stream_id)
             if warm_params is not None:
                 params = warm_params
-                opt = adamw_init(params)
+                opt = opt_init(params)
             else:
                 params, opt = cold_start(jax.random.fold_in(self.key, 1000 + stream_id), self.cfg)
             with self._mesh_ctx():
@@ -924,30 +957,32 @@ class RecoveryService:
 
     def _evict(self, slot: int, reason: str) -> StreamResult:
         """Read ``slot``'s result back and keep its params warm (span
-        ``mr.evict``, metadata from the host's slot view)."""
+        ``mr.evict``, metadata from the host's slot view): one ``slot_result``
+        program and one host read; the params stay on the device."""
         st = self.state
         with jax.profiler.TraceAnnotation(
             "mr.evict", stream_id=int(self._slot_view[slot]), slot=slot
         ):
-            sid = int(self._host_read(st.stream_id[slot]))
-            theta = st.theta[slot]
+            with self._mesh_ctx():
+                fields, slot_params = slot_result(st, jnp.int32(slot))
             if self.quant:
                 yw, uw = _slot_windows(
-                    st.buf_y[slot], st.buf_u[slot], st.mean[slot], st.scale[slot], self.scfg
+                    st.buf_y[slot], st.buf_u[slot], fields["mean"], fields["scale"], self.scfg
                 )
-                slot_params = jax.tree.map(lambda a: a[slot], st.params)
-                theta = readout_theta(slot_params, self.cfg, yw, uw, quant=True)
+                fields["theta"] = readout_theta(slot_params, self.cfg, yw, uw, quant=True)
+            got = self._host_read(fields)
+            sid = int(got["stream_id"])
             res = StreamResult(
                 stream_id=sid,
-                theta=self._host_read(theta),
-                mean=self._host_read(st.mean[slot]),
-                scale=self._host_read(st.scale[slot]),
-                steps=int(self._host_read(st.steps[slot])),
+                theta=got["theta"],
+                mean=got["mean"],
+                scale=got["scale"],
+                steps=int(got["steps"]),
                 reason=reason,
             )
             self.results[sid] = res
             self._undrained.append(res)
-            self._warm_put(sid, jax.tree.map(lambda a: a[slot], st.params))
+            self._warm_put(sid, slot_params)
         return res
 
     def _snapshot(self, status) -> list[StreamResult]:

@@ -481,3 +481,54 @@ def test_sharded_slots_parity_two_devices():
         """,
         n_devices=2,
     )
+
+
+def test_sharded_slots_eviction_parity_two_devices():
+    """Eviction on a sharded slot axis: ``slot_result`` gathers the slot from
+    the sharded state in one program, with one host read per eviction, and
+    the evicted answers and warm params match the trivial mesh's."""
+    run_devices(
+        """
+        import jax
+        import numpy as np
+        from repro import api
+        from repro.core.stream import StreamConfig
+        from repro.data.dynamics import generate_trajectory
+
+        _, ys, _ = generate_trajectory("lorenz", n_samples=200)
+        scfg = StreamConfig(buf_len=32, window=8, stride=8, chunk=8,
+                            steps_per_tick=4, min_steps=8, max_steps=8, delta_tol=0.0)
+
+        def run(mesh_slots):
+            spec = api.RecoverySpec(
+                state_dim=3, order=2, hidden=8, dense_hidden=16, dt=0.01,
+                encoder="gru", mode="stream", n_slots=2, stream=scfg,
+                mesh_slots=mesh_slots,
+            )
+            svc = api.compile_plan(spec).make_service()
+            for i in range(4):
+                svc.submit(i, ys[i : i + 32])
+            svc.fill_slots()
+            out = []
+            for t in range(4):
+                idx = 32 + t * 8 + np.arange(8)
+                info = svc.tick_once(np.repeat(ys[idx][None], 2, axis=0))
+                out += info["evicted"]
+                assert svc.sync_log[-1] == 4 + len(info["evicted"]), svc.sync_log
+            return svc, out
+
+        (svc1, ev1), (svc2, ev2) = run(1), run(2)
+        assert "slots" in str(svc2.state.theta.sharding)
+        assert [r.stream_id for r in ev2] == [r.stream_id for r in ev1] == [0, 1, 2, 3]
+        for a, b in zip(ev1, ev2):
+            assert a.steps == b.steps == 8
+            assert np.abs(a.theta - b.theta).max() < 1e-5
+            np.testing.assert_array_equal(a.mean, b.mean)
+            np.testing.assert_array_equal(a.scale, b.scale)
+        for sid in range(4):
+            for p1, p2 in zip(jax.tree.leaves(svc1.warm[sid]), jax.tree.leaves(svc2.warm[sid])):
+                assert np.abs(np.asarray(p1) - np.asarray(p2)).max() < 1e-5
+        print("PASS")
+        """,
+        n_devices=2,
+    )

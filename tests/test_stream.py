@@ -8,15 +8,17 @@ and int8-encoder readout parity with the f32 path.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import stream
-from repro.core.merinda import MRConfig
+from repro.core.merinda import MRConfig, init_mr
 from repro.core.stream import RecoveryService, StreamConfig
 from repro.data.dynamics import generate_trajectory
 from repro.data.windows import make_windows, n_buffer_windows, roll_buffer, window_views
+from repro.optim import adamw_init
 
 CFG = MRConfig(state_dim=3, order=2, hidden=8, dense_hidden=16, dt=0.01, encoder="gru")
 SCFG = StreamConfig(
@@ -126,6 +128,106 @@ def test_admission_preserves_other_slots(lorenz):
     # the admitted slot was fully reset
     assert float(np.asarray(svc.state.delta[0])) == np.inf
     assert int(np.asarray(svc.state.steps[0])) == 0
+
+
+# ---------------------------------------------------------------------------
+# compiled churn path: cold start and one-read eviction
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("seed", [0, 7, 2**31 + 5])
+def test_cold_start_program_matches_eager(seed):
+    """The compiled cold start gives the eager init_mr + adamw_init: params to
+    1e-6 relative, moments and step exactly zero."""
+    key = jax.random.fold_in(jax.random.key(seed), 1000 + 3)
+    params, opt = stream.cold_start(key, CFG)
+    ref = init_mr(key, CFG)
+    for got, want in zip(jax.tree.leaves(params), jax.tree.leaves(ref)):
+        got, want = np.asarray(got), np.asarray(want)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        assert np.abs(got - want).max() <= 1e-6 * max(np.abs(want).max(), 1e-30)
+    ref_opt = adamw_init(ref)
+    assert jax.tree.structure(opt) == jax.tree.structure(ref_opt)
+    assert int(opt.step) == 0
+    for leaf in jax.tree.leaves((opt.m, opt.v)):
+        assert leaf.dtype == jnp.float32 and not np.asarray(leaf).any()
+
+
+def test_cold_start_compiles_once(lorenz):
+    """After the first admission, further admissions reuse the one compiled
+    cold start: the jitted function's cache holds a single entry."""
+    stream.cold_start_program.clear_cache()
+    svc = RecoveryService(CFG, SCFG, n_slots=1, seed=0)
+    svc.submit(0, lorenz[:48])
+    svc.fill_slots()
+    assert stream.cold_start_program._cache_size() == 1
+    for sid in range(1, 9):
+        svc.submit(sid, lorenz[sid : sid + 48])
+        svc._admit_into(0)
+    assert svc.slot_streams() == [8]
+    assert stream.cold_start_program._cache_size() == 1
+
+
+def _five_reads(st, slot):
+    """A slot's answer read leaf by leaf, as eviction did before slot_result."""
+    return dict(
+        stream_id=int(np.asarray(st.stream_id[slot])),
+        theta=np.asarray(st.theta[slot]),
+        mean=np.asarray(st.mean[slot]),
+        scale=np.asarray(st.scale[slot]),
+        steps=int(np.asarray(st.steps[slot])),
+    )
+
+
+def test_evict_reads_slot_once(lorenz):
+    """On a service with evictions, each eviction is one host read: the
+    StreamResult matches the leaf-by-leaf reads of the slot, the warm
+    registry holds the slot's params, and a tick's syncs are the 4 status
+    reads of the composite tick plus one per eviction."""
+    svc = RecoveryService(CFG, SCFG, n_slots=2, seed=0)
+    for sid in range(6):
+        svc.submit(sid, lorenz[3 * sid : 3 * sid + SCFG.buf_len])
+    svc.fill_slots()
+    cursor, evictions = SCFG.buf_len, 0
+    for _ in range(12):
+        info = svc.tick_once(_chunks(lorenz, cursor, 2))
+        cursor += SCFG.chunk
+        evictions += len(info["evicted"])
+        assert svc.sync_log[-1] == 4 + len(info["evicted"])
+    assert evictions >= 2
+
+    # one direct eviction, against the five separate reads
+    slot = 1
+    st = svc.state
+    want = _five_reads(st, slot)
+    want_params = [np.asarray(a[slot]) for a in jax.tree.leaves(st.params)]
+    syncs = svc.counters["host_syncs"]
+    res = svc._evict(slot, "budget")
+    assert svc.counters["host_syncs"] == syncs + 1
+    assert res.stream_id == want["stream_id"] and res.steps == want["steps"]
+    assert res.reason == "budget"
+    for k in ("theta", "mean", "scale"):
+        np.testing.assert_array_equal(getattr(res, k), want[k])
+    warm = svc.warm[res.stream_id]
+    for got, exp in zip(jax.tree.leaves(warm), want_params):
+        np.testing.assert_array_equal(np.asarray(got), exp)
+    assert svc.results[res.stream_id] is res
+
+
+def test_slot_result_one_program_for_all_slots(lorenz):
+    """slot_result takes the slot as a traced index: every slot is served by
+    one compiled program, and the state is left intact (not donated)."""
+    svc = RecoveryService(CFG, SCFG, n_slots=3, seed=2)
+    for sid in range(3):
+        svc.submit(10 + sid, lorenz[sid : sid + SCFG.buf_len])
+    svc.fill_slots()
+    stream.slot_result.clear_cache()
+    for slot in range(3):
+        fields, params = stream.slot_result(svc.state, jnp.int32(slot))
+        assert int(fields["stream_id"]) == 10 + slot
+        np.testing.assert_array_equal(
+            np.asarray(params.head_w1), np.asarray(svc.state.params.head_w1[slot])
+        )
+    assert stream.slot_result._cache_size() == 1
+    assert np.asarray(svc.state.active).all()
 
 
 # ---------------------------------------------------------------------------
