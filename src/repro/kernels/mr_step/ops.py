@@ -14,7 +14,10 @@ scan, stream tick, serve_mr) shares one code path regardless of backend.
 
 Gradients flow through a custom_vjp whose backward is the reference program
 (same structure as kernels/gru_scan.ops), so every fused stage trains
-inside the scan-jitted engine exactly like the unfused one.
+inside the scan-jitted engine exactly like the unfused one. Every family's
+backward (the reference replay and its transpose) runs under the device
+scope ``mr.encoder_bwd`` (``ENCODER_BWD_SCOPE``), which reaches the compiled
+program only as ``op_name`` metadata, so a profiler trace can tell it apart.
 """
 
 from __future__ import annotations
@@ -29,6 +32,8 @@ from repro.core.quant import make_sigmoid_table, make_tanh_table, quantize_int8
 from repro.kernels import runtime as rt
 from repro.kernels.mr_step import kernel as _k
 from repro.kernels.mr_step import ref as _ref
+
+ENCODER_BWD_SCOPE = "mr.encoder_bwd"
 
 
 @_functools.partial(jax.custom_vjp, nondiff_argnums=(11, 12, 13))
@@ -58,8 +63,9 @@ def _mr_fwd(xs, h0, wx, wh, b, time_scale, dts, w1, b1, w2, b2, flow, act_bits, 
 
 
 def _mr_bwd(flow, act_bits, block_b, res, ct):
-    _, vjp = jax.vjp(lambda *a: _ref.mr_step_reference(*a, flow=flow, act_bits=act_bits), *res)
-    return vjp(ct)
+    with jax.named_scope(ENCODER_BWD_SCOPE):
+        _, vjp = jax.vjp(lambda *a: _ref.mr_step_reference(*a, flow=flow, act_bits=act_bits), *res)
+        return vjp(ct)
 
 
 _mr_step_cvjp.defvjp(_mr_fwd, _mr_bwd)
@@ -98,13 +104,14 @@ def _ltc_fwd(xs, h0, w_in, w_rec, bias, a, inv_tau, w1, b1, w2, b2, dt, n_subste
 
 
 def _ltc_bwd(dt, n_substeps, act_bits, block_b, res, ct):
-    _, vjp = jax.vjp(
-        lambda *args: _ref.mr_step_ltc_reference(
-            *args, dt=dt, n_substeps=n_substeps, act_bits=act_bits
-        ),
-        *res,
-    )
-    return vjp(ct)
+    with jax.named_scope(ENCODER_BWD_SCOPE):
+        _, vjp = jax.vjp(
+            lambda *args: _ref.mr_step_ltc_reference(
+                *args, dt=dt, n_substeps=n_substeps, act_bits=act_bits
+            ),
+            *res,
+        )
+        return vjp(ct)
 
 
 _mr_step_ltc_cvjp.defvjp(_ltc_fwd, _ltc_bwd)
@@ -144,13 +151,14 @@ def _node_fwd(xs, h0, w_f1, b_f1, w_f2, b_f2, w_in, b_in, w1, b1, w2, b2, dt, n_
 
 
 def _node_bwd(dt, n_substeps, act_bits, block_b, res, ct):
-    _, vjp = jax.vjp(
-        lambda *args: _ref.mr_step_node_reference(
-            *args, dt=dt, n_substeps=n_substeps, act_bits=act_bits
-        ),
-        *res,
-    )
-    return vjp(ct)
+    with jax.named_scope(ENCODER_BWD_SCOPE):
+        _, vjp = jax.vjp(
+            lambda *args: _ref.mr_step_node_reference(
+                *args, dt=dt, n_substeps=n_substeps, act_bits=act_bits
+            ),
+            *res,
+        )
+        return vjp(ct)
 
 
 _mr_step_node_cvjp.defvjp(_node_fwd, _node_bwd)

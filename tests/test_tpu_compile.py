@@ -10,8 +10,9 @@ lorenz/damped-oscillator/controlled-pendulum library: 3 states + 1 input,
 45 coefficients; 32-step windows, 17 windows per slot, 8-slot tick banks
 streamed over a 32-slot grid),
 and the compiled program must hold the Mosaic kernel (``tpu_custom_call``).
-The last test compiles the whole service tick with its slot axis sharded
-over two described chips.
+Two tests compile the whole service tick: with its slot axis sharded over
+two described chips, and at a tiny size to find the encoder's backward under
+its device scope in the compiled program's metadata.
 
 The topology is described inside a fixture — never at import — so every
 test worker collects the same tests and only the worker running this file
@@ -222,3 +223,60 @@ def test_slot_mesh_tick_compiles_for_v5e(topo, monkeypatch, tick_kernel):
         else:
             lowered = sm.tick.lower(state, new_y, new_u, key, cfg=cfg, scfg=scfg)
     assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("encoder", ["gru", "ltc"])
+def test_encoder_backward_runs_under_its_device_scope(one_chip, monkeypatch, encoder):
+    """The fused training tick, compiled for the chip at a tiny size: the
+    custom VJP's backward (reference replay and transpose) carries the
+    device scope ``mr.encoder_bwd`` in its ``op_name`` metadata, which is
+    what a profiler trace reads it by; the forward kernel does not."""
+    import re
+
+    from repro import api
+    from repro.core import stream as sm
+    from repro.core.stream import StreamConfig
+    from repro.kernels import runtime as rt
+    from repro.kernels.mr_step.ops import ENCODER_BWD_SCOPE
+
+    monkeypatch.setattr(rt, "on_tpu", lambda: True)
+    monkeypatch.setattr(
+        rt,
+        "resolve_dispatch",
+        lambda force_reference=False, interpret=None, backend=None: (
+            rt.Dispatch.REFERENCE if force_reference else rt.Dispatch.KERNEL
+        ),
+    )
+    n_slots = 4
+    spec = api.RecoverySpec(
+        state_dim=N_STATE,
+        input_dim=N_IN,
+        order=2,
+        hidden=8,
+        dense_hidden=16,
+        dt=0.01,
+        encoder=encoder,
+        fused=True,
+        mode="stream",
+        n_slots=n_slots,
+        stream=StreamConfig(steps_per_tick=1, buf_len=48, window=16, stride=8, chunk=8),
+    )
+    cfg, scfg = spec.to_mr_config(), spec.stream_config()
+    state = jax.eval_shape(lambda k: sm.init_slots(k, cfg, scfg, n_slots), jax.random.key(0))
+    state = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), state)
+    new_y = jax.ShapeDtypeStruct((n_slots, scfg.chunk, N_STATE), jnp.float32, sharding=one_chip)
+    new_u = jax.ShapeDtypeStruct((n_slots, scfg.chunk, N_IN), jnp.float32, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=one_chip)
+    text = sm.tick.lower(state, new_y, new_u, key, cfg=cfg, scfg=scfg).compile().as_text()
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    assert sum(ENCODER_BWD_SCOPE in n for n in op_names) > 10
+    kernels = [
+        line
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+        and line.lstrip().startswith("%mr_step_fused")
+    ]
+    assert kernels  # the training step's forward and the readout
+    for line in kernels:
+        name = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert ENCODER_BWD_SCOPE not in name, name
